@@ -217,7 +217,10 @@ def run_distributed(
 
     enable_persistent_compilation_cache()
     if multihost:
-        jax.distributed.initialize()
+        jax.distributed.initialize()  # before any backend query
+    from sagecal_tpu.utils.platform import accelerator
+
+    accelerator()  # no TPU and no explicit CPU choice: refuse to run
     if datasets is None:
         datasets = sorted(glob.glob(cfg.dataset))
     if not datasets:
@@ -686,6 +689,13 @@ def _run_distributed_inner(
                 p_bands, rho, jnp.asarray(B_pad, dtype),
             )
         p_bands = out.p  # warm start the next tile (reference keeps p)
+        if pi == 0:
+            # where the mesh placed the bands (padding bands included)
+            placed = out.p.sharding.devices_indices_map(out.p.shape)
+            for d, idx in sorted(placed.items(), key=lambda kv: kv[0].id):
+                band = range(Nf_pad)[idx[0]]
+                log(f"distributed: {d} holds bands "
+                    f"{band.start}..{band.stop - 1}")
         if diffuse_idx is not None:
             zdiff_carry = out.Zspat_diff  # lazy device array, no sync
         # overlap: prepare tile t+1 (I/O + coherency dispatch) while

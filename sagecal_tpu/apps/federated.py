@@ -86,6 +86,9 @@ def run_federated(
     from sagecal_tpu.obs.perf import enable_persistent_compilation_cache
 
     enable_persistent_compilation_cache()
+    from sagecal_tpu.utils.platform import accelerator
+
+    accelerator()  # no TPU and no explicit CPU choice: refuse to run
     if datasets is None:
         datasets = sorted(glob.glob(cfg.dataset))
     if not datasets:

@@ -227,15 +227,10 @@ def run_worker(cfg: FleetConfig, log=print):
 
     from sagecal_tpu.fleet.worker import FleetWorker
     from sagecal_tpu.obs.perf import enable_persistent_compilation_cache
-    from sagecal_tpu.utils.platform import cpu_device
+    from sagecal_tpu.utils.platform import accelerator, cpu_device
 
     enable_persistent_compilation_cache()
-    try:
-        accel = jax.devices()[0]
-    except RuntimeError:
-        accel = None
-    if accel is not None and accel.platform == "cpu":
-        accel = None
+    accel = accelerator()
     elog = _obs_setup(cfg, "worker")
     try:
         with jax.default_device(cpu_device()):
@@ -276,6 +271,14 @@ def main(argv=None) -> int:
             build_parser().error("--queue-dir (or --out-dir) required")
         run_worker(cfg)
         return 0
+    from sagecal_tpu.fleet.coordinator import check_worker_count
+    from sagecal_tpu.utils.platform import host_only
+
+    try:
+        check_worker_count(cfg)
+    except ValueError as e:
+        build_parser().error(str(e))
+    host_only()  # the workers own the chip; seeding runs on the host
     requests = None
     if args.synthetic > 0:
         from sagecal_tpu.serve.request import load_requests

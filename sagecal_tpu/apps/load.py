@@ -124,6 +124,9 @@ def main(argv=None) -> int:
     spec = spec_from_args(args)
     from sagecal_tpu.apps.fleet import _obs_setup, _obs_teardown
     from sagecal_tpu.fleet.loadgen import LoadRunner
+    from sagecal_tpu.utils.platform import host_only
+
+    host_only()  # the fleet's workers own the chip
 
     elog = _obs_setup(cfg, "loadgen")
     try:

@@ -66,6 +66,9 @@ def run_minibatch(cfg: RunConfig, log=print):
     from sagecal_tpu.utils.profiling import trace
 
     enable_persistent_compilation_cache()
+    from sagecal_tpu.utils.platform import accelerator
+
+    accelerator()  # no TPU and no explicit CPU choice: refuse to run
     audit = TransferAudit()
     with trace(), audit:
         return _run_minibatch(cfg, log, audit)

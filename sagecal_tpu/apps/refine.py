@@ -168,6 +168,10 @@ def _build_problem(cfg: RefineConfig, spec, log):
 
 def run_refine_app(cfg: RefineConfig, log=print) -> dict:
     """Run one refinement to completion; returns the result summary."""
+    from sagecal_tpu.utils.platform import accelerator
+
+    accelerator()  # no TPU and no explicit CPU choice: refuse to run
+
     from sagecal_tpu.elastic import (
         CheckpointManager,
         config_fingerprint,

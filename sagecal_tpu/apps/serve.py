@@ -119,15 +119,10 @@ def run_serve(cfg: ServeConfig, requests=None, log=print):
     import jax
 
     from sagecal_tpu.obs.perf import enable_persistent_compilation_cache
-    from sagecal_tpu.utils.platform import cpu_device
+    from sagecal_tpu.utils.platform import accelerator, cpu_device
 
     enable_persistent_compilation_cache()
-    try:
-        accel = jax.devices()[0]
-    except RuntimeError:
-        accel = None
-    if accel is not None and accel.platform == "cpu":
-        accel = None
+    accel = accelerator()
     with jax.default_device(cpu_device()):
         return _run_serve_host(cfg, requests, log, accel)
 

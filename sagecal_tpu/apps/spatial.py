@@ -197,6 +197,10 @@ def _solve_bands(cfg: SpatialConfig, datas, clusters, manager, elog, log):
 
 def run_spatial(cfg: SpatialConfig, log=print) -> dict:
     """Run the spatial pipeline to completion; returns the summary."""
+    from sagecal_tpu.utils.platform import accelerator
+
+    accelerator()  # no TPU and no explicit CPU choice: refuse to run
+
     import jax.numpy as jnp
 
     from sagecal_tpu.elastic import CheckpointManager, config_fingerprint

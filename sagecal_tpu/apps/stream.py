@@ -115,15 +115,10 @@ def run_stream(cfg: StreamConfig, log=print):
         emit_perf_events, enable_persistent_compilation_cache,
     )
     from sagecal_tpu.obs.trace import close_tracer, configure_tracer
-    from sagecal_tpu.utils.platform import cpu_device
+    from sagecal_tpu.utils.platform import accelerator, cpu_device
 
     enable_persistent_compilation_cache()
-    try:
-        accel = jax.devices()[0]
-    except RuntimeError:
-        accel = None
-    if accel is not None and accel.platform == "cpu":
-        accel = None
+    accel = accelerator()
     manifest = RunManifest.collect(
         kernel_path="xla", app="stream", dataset=cfg.dataset,
         window=cfg.window, hop=cfg.hop, warm_start=cfg.warm_start,

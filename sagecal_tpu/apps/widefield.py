@@ -170,15 +170,10 @@ def run_widefield(cfg: WidefieldConfig, log=print) -> dict:
         emit_perf_events, enable_persistent_compilation_cache,
     )
     from sagecal_tpu.obs.trace import close_tracer, configure_tracer
-    from sagecal_tpu.utils.platform import cpu_device
+    from sagecal_tpu.utils.platform import accelerator, cpu_device
 
     enable_persistent_compilation_cache()
-    try:
-        accel = jax.devices()[0]
-    except RuntimeError:
-        accel = None
-    if accel is not None and accel.platform == "cpu":
-        accel = None
+    accel = accelerator()
     manifest = RunManifest.collect(
         kernel_path="xla", app="widefield", nsources=cfg.nsources,
         nclusters=cfg.nclusters, ntiles=cfg.ntiles, order=cfg.order,

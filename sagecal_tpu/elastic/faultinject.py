@@ -1,7 +1,7 @@
 """Fault-injection harness: prove elastic resume by actually killing runs.
 
-Used by tests/test_elastic.py and tpu_kernel_check.sh's kill-and-resume
-smoke step.  The harness runs a calibration as a SUBPROCESS (so SIGTERM
+Used by tests/test_elastic.py and by hand (verify skill) for the
+kill-and-resume drives.  The harness runs a calibration as a SUBPROCESS (so SIGTERM
 exercises the real signal path: obs/flight.py's handler runs the crash
 flushers — final checkpoint write, prefetcher teardown, event-log
 run_aborted — then re-delivers the signal), kills it either at a tile
@@ -179,7 +179,7 @@ def interrupted_run_matches(
 def main(argv=None):
     """``python -m sagecal_tpu.elastic.faultinject kill-at-ckpt N
     CKPT_DIR -- cmd...`` / ``kill-after SECONDS -- cmd...`` — the shell
-    entry tpu_kernel_check.sh uses."""
+    entry of the kill-and-resume drives."""
     argv = sys.argv[1:] if argv is None else list(argv)
     if len(argv) < 2:
         print(__doc__, file=sys.stderr)

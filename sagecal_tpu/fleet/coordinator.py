@@ -127,10 +127,28 @@ def worker_argv(cfg, index: int) -> List[str]:
     return argv
 
 
+def check_worker_count(cfg) -> None:
+    """Refuse a fleet its host cannot run.  Every worker solves on
+    ``jax.devices()[0]``, and a process that initializes the TPU takes
+    every chip of its host, so a second TPU worker on the same host
+    could not start.  Only an explicit ``JAX_PLATFORMS=cpu`` (read from
+    the environment the workers inherit: the coordinator itself never
+    touches JAX) runs several workers side by side."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    most = max(int(cfg.workers), int(getattr(cfg, "max_workers", 0) or 0))
+    if most > 1:
+        raise ValueError(
+            f"fleet: {most} workers requested, but a TPU worker takes "
+            f"every chip of its host, so one host runs one worker; use "
+            f"--workers 1 (and --max-workers 1), or JAX_PLATFORMS=cpu")
+
+
 class FleetCoordinator:
     """Seed + spawn + watch + report."""
 
     def __init__(self, cfg, log=print, clock=time.time):
+        check_worker_count(cfg)
         self.cfg = cfg
         self.log = log
         self.clock = clock  # injectable so watch deadlines are checkable

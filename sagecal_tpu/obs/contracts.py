@@ -65,7 +65,7 @@ def checkify_active() -> bool:
         return False
     import jax.core
 
-    return jax.core.trace_state_clean()
+    return jax.core.trace_ctx.is_top_level()
 
 
 def error_set():

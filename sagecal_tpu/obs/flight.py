@@ -1,8 +1,7 @@
 """In-process flight recorder: ring buffer, heartbeat, hang watchdog,
 crash dumps.
 
-The watch scripts (``tpu_watch.sh`` and friends) can only observe a run
-from outside; when a run hangs in a wedged collective or dies on an
+An outside watcher can only observe a run from outside; when a run hangs in a wedged collective or dies on an
 uncaught exception, the interesting state is *inside* the process.  A
 :class:`FlightRecorder` keeps:
 
@@ -245,9 +244,9 @@ class FlightRecorder:
     # -- heartbeat ----------------------------------------------------
 
     def heartbeat(self, closed: bool = False) -> None:
-        """Atomically rewrite the heartbeat file.  Watch scripts key on
-        the file *mtime* (see tpu_watch.sh); the JSON body carries the
-        richer state for humans and ``diag``."""
+        """Atomically rewrite the heartbeat file.  A watcher keys on
+        the file *mtime*; the JSON body carries the richer state for
+        humans and ``diag``."""
         doc = {
             "pid": os.getpid(),
             "ts": time.time(),

@@ -32,7 +32,6 @@ from sagecal_tpu.parallel import consensus
 from sagecal_tpu.parallel.admm import admm_sagefit
 from sagecal_tpu.parallel.manifold import manifold_average_projectback
 from sagecal_tpu.solvers.lm import LMConfig
-from sagecal_tpu.utils.platform import shard_map as _shard_map
 
 
 class FederatedResult(NamedTuple):
@@ -160,7 +159,7 @@ def make_federated_mesh_fn(
             raise ValueError(
                 f"sub-band axis {p0.shape[0]} != mesh size {ndev}"
             )
-        sm = _shard_map(
+        sm = jax.shard_map(
             lambda d, c, p, r, b: local_loop(
                 jax.tree_util.tree_map(lambda x: x[0], d),
                 jax.tree_util.tree_map(lambda x: x[0], c),
@@ -274,7 +273,7 @@ def make_federated_minibatch_fn(
 
     @jax.jit
     def fn(data_stack, cdata_stack, state, rho, B):
-        sm = _shard_map(
+        sm = jax.shard_map(
             lambda d, c, s, r, b: local_step(
                 jax.tree_util.tree_map(lambda x: x[0], d),
                 jax.tree_util.tree_map(lambda x: x[0], c),
@@ -312,7 +311,7 @@ def make_fed_avg_fn(mesh: Mesh, axis_name: str = "freq",
 
     @jax.jit
     def fn(state):
-        return _shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=(fspec,), out_specs=fspec,
             check_vma=True,
         )(state)

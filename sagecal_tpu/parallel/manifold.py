@@ -24,10 +24,26 @@ import jax.numpy as jnp
 
 
 def polar_unitary_2x2(A):
-    """U V^H from the SVD of trailing 2x2 complex matrices (the unitary
-    polar factor).  Batched; uses jnp.linalg.svd on 2x2s."""
-    U, _, Vh = jnp.linalg.svd(A)
-    return U @ Vh
+    """The unitary polar factor U V^H of trailing 2x2 complex matrices,
+    in closed form: U = (A + (d/|d|) adj(A)^H) / sqrt(||A||_F^2 + 2|d|)
+    with d = det A (Cayley-Hamilton on A A^H gives A A^H A + |d|^2
+    A^-H = ||A||_F^2 A).  Elementwise, so it runs inside shard_map on
+    any backend — the TPU's iterative SVD carries loop state whose
+    varying-axes type the shard_map checker rejects."""
+    a, b = A[..., 0, 0], A[..., 0, 1]
+    c, e = A[..., 1, 0], A[..., 1, 1]
+    d = a * e - b * c
+    dabs = jnp.abs(d)
+    ph = d / jnp.where(dabs > 0, dabs, 1)
+    # adj(A)^H = [[conj(e), -conj(c)], [-conj(b), conj(a)]]
+    adjh = jnp.stack([
+        jnp.stack([jnp.conj(e), -jnp.conj(c)], -1),
+        jnp.stack([-jnp.conj(b), jnp.conj(a)], -1),
+    ], -2)
+    fro2 = jnp.sum(jnp.abs(A) ** 2, axis=(-2, -1))
+    t = jnp.sqrt(fro2 + 2 * dabs)
+    t = jnp.where(t > 0, t, 1)
+    return (A + ph[..., None, None] * adjh) / t[..., None, None]
 
 
 def procrustes_project(J, J_ref):

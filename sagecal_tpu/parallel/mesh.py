@@ -60,7 +60,6 @@ from sagecal_tpu.parallel.admm import admm_sagefit, factor_schedule
 from sagecal_tpu.parallel.manifold import manifold_average
 from sagecal_tpu.solvers.lm import LMConfig
 from sagecal_tpu.solvers.sage import SM_LM_LBFGS, ClusterData
-from sagecal_tpu.utils.platform import shard_map as _shard_map
 
 
 class AdmmResult(NamedTuple):
@@ -767,7 +766,7 @@ def make_admm_mesh_fn(
                 f"sub-band count {Nf} must be a multiple of the mesh size "
                 f"{ndev}; pad with zero-weight bands (rho=0, mask=0) first"
             )
-        sm = _shard_map(
+        sm = jax.shard_map(
             local_loop,
             mesh=mesh,
             in_specs=(fspec, fspec, fspec, fspec, fspec),

@@ -35,7 +35,7 @@ Hit/miss counters live in two places on purpose:
 
 Without a store this module behaves exactly as before (the legacy
 cross-process layer is the persistent XLA compilation cache,
-``SAGECAL_COMPILE_CACHE``, obs/perf.py): a restarted server misses
+obs/perf.py ``enable_persistent_compilation_cache``): a restarted server misses
 here on first touch of each bucket but deserializes yesterday's HLO
 instead of recompiling from scratch.
 """

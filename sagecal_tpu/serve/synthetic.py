@@ -1,7 +1,6 @@
 """Synthetic multi-tenant request workloads.
 
-``sagecal-tpu serve --synthetic N`` (and the serve smoke in
-tpu_kernel_check.sh, and the throughput bench) need a reproducible
+``sagecal-tpu serve --synthetic N`` (and the throughput bench) need a reproducible
 mixed-shape request mix without real observations on disk.  This
 module simulates small datasets across a couple of shape classes and
 writes a request manifest spread over a few tenants — enough to

@@ -26,7 +26,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from sagecal_tpu.utils.platform import shard_map
 
 from sagecal_tpu.core.types import VisData
 from sagecal_tpu.obs.perf import instrumented_jit
@@ -190,7 +189,7 @@ def make_sharded_joint_fn(
             nonfinite_count=P(), station_amp=P(), station_amp_spread=P(),
             station_phase_spread=P(), identity_departure=P(),
         ),)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fit,
         mesh=mesh,
         in_specs=(data_specs, cdata_specs, P()),

@@ -154,6 +154,18 @@ class TestManifold:
             np.asarray(eye), np.broadcast_to(np.eye(2), (5, 2, 2)), atol=1e-6
         )
 
+    def test_polar_factor_matches_svd(self):
+        """The closed form equals U V^H of the SVD (the reference's
+        zgesvd route), including near-singular blocks."""
+        rng = np.random.default_rng(8)
+        A = rng.standard_normal((64, 2, 2)) + 1j * rng.standard_normal(
+            (64, 2, 2))
+        A[0, 1] = 1e-6 * A[0, 1] + A[0, 0]  # det ~ 1e-6
+        U, _, Vh = np.linalg.svd(A)
+        np.testing.assert_allclose(
+            np.asarray(polar_unitary_2x2(jnp.asarray(A))), U @ Vh,
+            atol=1e-8)
+
     def test_procrustes_undoes_unitary(self):
         rng = np.random.default_rng(5)
         N = 6

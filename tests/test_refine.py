@@ -132,8 +132,8 @@ def test_flux_recovery_through_calibration(sky, problem, implicit_vg):
     """Acceptance: a 15%-perturbed source flux comes back to <1% rel
     error THROUGH the inner gain solve (gains are free and must
     re-converge at every outer step).  Slow tier; the fast proof of the
-    same bar is the tpu_kernel_check.sh refine smoke (3 outer CLI steps
-    -> flux_err < 1%)."""
+    same bar is the verify skill's refine CLI drive (3 outer steps ->
+    flux_err < 1%)."""
     true_flux = float(sky.true_flux[0][0])
     theta0 = problem.spec.theta0(problem.clusters, problem.tables)
     assert abs(float(theta0[0]) - true_flux) / true_flux >= 0.10

@@ -3,9 +3,8 @@ FISTA spatial fit (apps/spatial.py over parallel/spatial.py), end to
 end on the shared simulated-sky fixtures, plus checkpoint/resume
 bit-exactness (an in-process kill simulation and the real SIGTERM
 subprocess round).  The numeric oracles run in the fast tier; every
-test that pays for band solves is slow-marked — the tpu_kernel_check.sh
-spatial smoke drives the app (including kill-and-resume) on every
-verify run.
+test that pays for band solves is slow-marked; the verify skill's
+spatial CLI drive covers the app end to end, kill-and-resume included.
 """
 
 import json
@@ -84,9 +83,8 @@ def test_fista_recovers_exact_spatial_model():
 def test_spatial_app_end_to_end(tmp_path):
     """Full pipeline on the multiband fixture: solves converge, the MDL
     scan runs, the FISTA fit explains the consensus solutions, outputs
-    land on disk.  Slow tier (band solves + compiles); every verify run
-    still drives the app end to end via the tpu_kernel_check.sh spatial
-    smoke."""
+    land on disk.  Slow tier (band solves + compiles); the verify
+    skill's spatial CLI drive covers the fast path."""
     cfg = _cfg(tmp_path)
     summary = run_spatial(cfg, log=lambda *a: None)
     assert summary["bands"] == 3 and summary["npoly"] == 2

@@ -6,8 +6,8 @@ The table is the banked output of the symbolic VMEM footprint model
 the derived ``FULL_CLUSTER_TILE``, and the per-dtype batched row
 bounds that ``solvers/batched.py::batch_rows_bound`` reads at runtime
 instead of hardcoded constants.  It is fingerprinted with the sha256
-of ``ops/rime_kernel.py`` so CI (``tpu_kernel_check.sh`` and ``diag
-kernelcheck``) can prove the artifact matches the kernels it claims to
+of ``ops/rime_kernel.py`` so ``diag kernelcheck`` (and this tool's
+``--check``) can prove the artifact matches the kernels it claims to
 describe.
 
 Usage::
