@@ -342,7 +342,6 @@ def run(dtype=np.float32, repeats=REPEATS, want_flops=False, tilesz=TILESZ,
         step = make_step(data, cdata)
     from sagecal_tpu.obs.devprof import device_profile
     from sagecal_tpu.obs.perf import device_memory_snapshot, note_compile
-    from sagecal_tpu.utils.profiling import trace
 
     perf = {"flops": None, "bytes_accessed": None,
             "peak_device_memory_bytes": None}
@@ -374,13 +373,10 @@ def run(dtype=np.float32, repeats=REPEATS, want_flops=False, tilesz=TILESZ,
             step = compiled
         except Exception:
             pass
-    # SAGECAL_PROFILE_DIR additionally captures an XLA trace of the
-    # warm-up + timing loop (no-op when unset); SAGECAL_DEVICE_PROFILE /
-    # --device-profile captures the devprof trace our own roofline
-    # parser ingests (`diag roofline`).  Only one jax trace can be live
-    # — device_profile skips itself (with a flight note) when the
-    # TensorBoard trace already owns the profiler.
-    with trace(), device_profile():
+    # SAGECAL_DEVICE_PROFILE / --device-profile captures a profiler
+    # trace of the warm-up + timing loop (no-op when unset), for
+    # TensorBoard and our own roofline parser (`diag roofline`)
+    with device_profile():
         out = step(*args)  # compile (if not AOT) + first run
         iters = int(np.asarray(out[2]))  # host read = the only real sync
         times = []

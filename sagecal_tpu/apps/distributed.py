@@ -558,14 +558,9 @@ def _run_distributed_inner(
         for path in datasets
     ]
     from sagecal_tpu.obs.perf import TransferAudit, emit_perf_events
-    from sagecal_tpu.utils.profiling import PhaseTimer, trace
+    from sagecal_tpu.utils.profiling import PhaseTimer
 
     timer = PhaseTimer()
-    # manual enter so the existing try/finally below owns the exits
-    # (exception-safe: a crash still flushes a loadable XLA trace)
-    trace_cm = trace()
-    if trace_cm.__enter__():
-        log("profiling: XLA trace enabled")
     audit = TransferAudit()
     audit.__enter__()
 
@@ -843,11 +838,10 @@ def _run_distributed_inner(
     finally:
         # reap every band's prefetch thread even on a mid-loop failure;
         # the audit exit is idempotent (already closed on the happy
-        # path above) and the trace CM only stops a trace it started
+        # path above)
         for pf in prefetchers:
             pf.__exit__(None, None, None)
         audit.__exit__(None, None, None)
-        trace_cm.__exit__(None, None, None)
         run_span.__exit__(None, None, None)
         # writes the Chrome trace (trace.json) alongside the span JSONL
         close_tracer()

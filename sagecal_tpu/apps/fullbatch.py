@@ -298,9 +298,10 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
             precess=not is_alo,
         )
 
-    # first-class profiling (SURVEY section 5): per-phase wall-clock
-    # always on; SAGECAL_PROFILE_DIR additionally captures an XLA trace
-    # and SAGECAL_TRANSFER_AUDIT=1 logs implicit host<->device transfers
+    # first-class profiling (SURVEY section 5): per-phase wall-clock,
+    # each phase and tile also a profiler annotation (USER_MANUAL
+    # "Reading the tile line"); SAGECAL_TRANSFER_AUDIT=1 logs implicit
+    # host<->device transfers
     from sagecal_tpu.obs.contracts import (
         ContractViolation,
         emit_contract_events,
@@ -309,17 +310,11 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
         TransferAudit,
         dump_memory_profile,
         emit_perf_events,
+        jit_clock,
     )
-    from sagecal_tpu.utils.profiling import PhaseTimer, trace
+    from sagecal_tpu.utils.profiling import PhaseTimer
 
     timer = PhaseTimer()
-    # entered by hand (not `with`) so the existing try/finally below can
-    # own the exits without reindenting the whole tile loop; the finally
-    # guarantees a crashed run still flushes a loadable trace
-    trace_cm = trace()
-    trace_dir = trace_cm.__enter__()
-    if trace_dir:
-        log(f"profiling: XLA trace -> {trace_dir}")
     audit = TransferAudit()
     audit.__enter__()
 
@@ -342,8 +337,8 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
     # Background-thread tile prefetch (io/dataset.py TilePrefetcher):
     # the next tile's HDF5 read + packing overlaps this tile's solve —
     # the reference's loadData-around-the-pipeline role.  The "load"
-    # profiling phase therefore measures the prefetch STALL, not the
-    # raw read.
+    # profiling phase therefore measures the prefetch STALL; the read
+    # itself is the worker's, printed as read_s.
     prefetch_cm = TilePrefetcher(cfg.dataset, [t0 for _, t0 in pairs],
                                  specs, cfg.tilesz, depth=1)
     # root span of the run; manual enter — the try/finally owns the exit
@@ -361,18 +356,21 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
           precompute role, fullbatch_mode.cpp:371-388).  Coherencies
           depend only on u/v/w/freqs, so whitening (vis/mask-only) can
           be applied later without invalidating them."""
-          t0_chk, tiles = next(prefetch)
+          with timer.phase("load"):
+              t0_chk, tiles = next(prefetch)
           if t0_chk != t0:
               raise RuntimeError(
                   f"prefetch order mismatch: got tile {t0_chk}, "
                   f"expected {t0}"
               )
+          timer.note("read_s", prefetch_cm.read_seconds(t0))
           full_ = tiles[0]
           data_ = None if cfg.simulation_mode else tiles[1]
-          cdata_full_ = _cdata(
-              full_, t0, fdelta=meta.deltaf / max(meta.nchan, 1)
-          )
-          cdata_ = None if cfg.simulation_mode else _cdata(data_, t0)
+          with timer.phase("coh"):
+              cdata_full_ = _cdata(
+                  full_, t0, fdelta=meta.deltaf / max(meta.nchan, 1)
+              )
+              cdata_ = None if cfg.simulation_mode else _cdata(data_, t0)
           return full_, data_, cdata_full_, cdata_
 
       def _ckpt_update(pi):
@@ -388,7 +386,12 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
               tiles_done=resume_done + pi + 1, run_id=manifest.run_id,
           )
 
+      def _end_tile():
+          tile_ann.__exit__(None, None, None)
+          tile_span.__exit__(None, None, None)
+
       prepared = None
+      jit_mark = jit_clock()
       if pairs:
           with timer.phase("load+coh"):
               prepared = _prepare(pairs[0][1])
@@ -396,6 +399,8 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
         tic = time.time()
         tile_span = tracer.span("tile", kind="tile", tile=t0)
         tile_span.__enter__()
+        tile_ann = timer.tile(t0)
+        tile_ann.__enter__()
         full, data, cdata_full, cdata = prepared
 
         if cfg.simulation_mode:
@@ -423,7 +428,7 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                           seconds=time.time() - tic,
                           phase_seconds=timer.tile_timings())
             log(f"tile {t0}: simulated ({time.time()-tic:.1f}s)")
-            tile_span.__exit__(None, None, None)
+            _end_tile()
             continue
 
         if cfg.whiten:
@@ -442,51 +447,61 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                 prepared = _prepare(pairs[pi + 1][1])
         with timer.phase("solve-wait"):
             res0, res1 = float(out.res_0), float(out.res_1)
-        # divergence guard (fullbatch_mode.cpp:618-632)
-        diverged = (
-            not np.isfinite(res1) or res1 == 0.0 or res1 > cfg.res_ratio * res0
-        )
-        # out.p comes home as real numpy so all downstream eager math
-        # (params_to_jones, residuals) stays on the CPU device
-        p = pinit if diverged else jnp.asarray(np.asarray(out.p))
-        # advance the tile RNG chain (the tile just solved used the
-        # pre-advance key; a resumed run restores this chain from the
-        # checkpoint, so resume == uninterrupted bit-for-bit)
-        rng_key = jax.random.fold_in(rng_key, tile_no)
-        if diverged:
-            log(f"tile {t0}: diverged ({res0:.3e} -> {res1:.3e}), reset")
-
-        # quality watchdog (obs/quality.py): chi^2 attribution + gain
-        # health of this tile's solve -> solve_quality event + gauges,
-        # escalating to quality_degraded / solver_diverged.  The
-        # residual-ratio guard above joins the same verdict so
-        # --abort-on-divergence covers both detectors.
-        from sagecal_tpu.obs.quality import abort_if_diverged, check_and_emit
-
-        q_verdict, q_reasons = "ok", []
-        if out.quality is not None:
-            # coh_dtype rides on every quality event so a degraded bf16
-            # run is attributable to the precision knob at a glance
-            q_verdict, q_reasons = check_and_emit(
-                elog, out.quality, log=log, tile=t0, app="fullbatch",
-                coh_dtype=scfg.coh_dtype,
+        with timer.phase("finish"):
+            # the solve's own step counts (solvers/sage.SageResult.steps)
+            steps = {k: int(v) for k, v in out.steps.items()}
+            mean_nu = float(out.mean_nu)
+            # divergence guard (fullbatch_mode.cpp:618-632)
+            diverged = (
+                not np.isfinite(res1) or res1 == 0.0
+                or res1 > cfg.res_ratio * res0
             )
-        if diverged:
-            if q_verdict != "diverged" and elog is not None:
-                elog.emit("solver_diverged",
-                          reasons=[f"residual_ratio:{res0:.3e}->{res1:.3e}"],
-                          tile=t0, app="fullbatch")
-            q_verdict = "diverged"
-            q_reasons = q_reasons + [
-                f"residual_ratio:{res0:.3e}->{res1:.3e}"
-            ]
-        if cfg.abort_on_divergence:
-            abort_if_diverged(elog, q_verdict, q_reasons,
-                              tile=t0, app="fullbatch")
+            # out.p comes home as real numpy so all downstream eager math
+            # (params_to_jones, residuals) stays on the CPU device
+            p = pinit if diverged else jnp.asarray(np.asarray(out.p))
+            # advance the tile RNG chain (the tile just solved used the
+            # pre-advance key; a resumed run restores this chain from the
+            # checkpoint, so resume == uninterrupted bit-for-bit)
+            rng_key = jax.random.fold_in(rng_key, tile_no)
+            if diverged:
+                log(f"tile {t0}: diverged ({res0:.3e} -> {res1:.3e}), "
+                    f"reset")
 
-        # append solution columns (fullbatch_mode.cpp:595-605)
-        jsol = np.asarray(params_to_jones(p)).reshape(M * nchunk_max, N, 2, 2)
-        solio.append_solutions(sol_fh, jsol)
+            # quality watchdog (obs/quality.py): chi^2 attribution + gain
+            # health of this tile's solve -> solve_quality event + gauges,
+            # escalating to quality_degraded / solver_diverged.  The
+            # residual-ratio guard above joins the same verdict so
+            # --abort-on-divergence covers both detectors.
+            from sagecal_tpu.obs.quality import (
+                abort_if_diverged, check_and_emit,
+            )
+
+            q_verdict, q_reasons = "ok", []
+            if out.quality is not None:
+                # coh_dtype rides on every quality event so a degraded bf16
+                # run is attributable to the precision knob at a glance
+                q_verdict, q_reasons = check_and_emit(
+                    elog, out.quality, log=log, tile=t0, app="fullbatch",
+                    coh_dtype=scfg.coh_dtype,
+                )
+            if diverged:
+                if q_verdict != "diverged" and elog is not None:
+                    elog.emit(
+                        "solver_diverged",
+                        reasons=[f"residual_ratio:{res0:.3e}->{res1:.3e}"],
+                        tile=t0, app="fullbatch")
+                q_verdict = "diverged"
+                q_reasons = q_reasons + [
+                    f"residual_ratio:{res0:.3e}->{res1:.3e}"
+                ]
+            if cfg.abort_on_divergence:
+                abort_if_diverged(elog, q_verdict, q_reasons,
+                                  tile=t0, app="fullbatch")
+
+            # append solution columns (fullbatch_mode.cpp:595-605)
+            jsol = np.asarray(params_to_jones(p)).reshape(
+                M * nchunk_max, N, 2, 2)
+            solio.append_solutions(sol_fh, jsol)
 
         if cfg.influence:
             # -i: influence function replaces the residuals
@@ -503,7 +518,7 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                 f"({time.time()-tic:.1f}s)")
             results.append((float(out.res_0), float(out.res_1)))
             _ckpt_update(pi)
-            tile_span.__exit__(None, None, None)
+            _end_tile()
             continue
 
         if cfg.per_channel and meta.nchan > 1:
@@ -543,43 +558,37 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                 )))
         with timer.phase("write"):
             ds.write_tile(t0, np.asarray(res), column=cfg.out_column)
-        # warm-start accounting: gains carry tile-to-tile (temporal
-        # smoothness), so iterations-to-converge per tile is the
-        # measured win; gauge + tile_done field feed `diag prom` and
-        # the bench's warm_start_speedup
-        warm_start = bool(pi > 0 or resume_done > 0
-                          or cfg.init_solutions)
-        iters_tile = None
-        conv_recs = sage_convergence_records(out.telemetry)
-        if conv_recs:
-            iters_tile = int(sum(int(r.get("iterations", 0))
-                                 for r in conv_recs))
-            from sagecal_tpu.obs.registry import get_registry
-
-            get_registry().gauge_set(
-                "tile_iterations_to_converge", iters_tile,
-                help="summed solver iterations of this tile's solve "
-                     "(warm starts shrink it)", tile=str(t0),
-                warm_start=str(int(warm_start)))
-        if elog is not None:
-            for rec in conv_recs:
-                elog.emit("cluster_convergence", tile=t0, **rec)
-            elog.emit(
-                "tile_done", tile=t0, res0=res0, res1=res1,
-                mean_nu=float(out.mean_nu), diverged=bool(diverged),
-                seconds=time.time() - tic,
-                warm_start=warm_start, iterations=iters_tile,
-                phase_seconds=timer.tile_timings(),
-            )
+        with timer.phase("finish"):
+            # warm-start accounting: gains carry tile-to-tile (temporal
+            # smoothness), so the solver steps per tile are the measured
+            # win; tile_done carries them
+            warm_start = bool(pi > 0 or resume_done > 0
+                              or cfg.init_solutions)
+            if elog is not None:
+                for rec in sage_convergence_records(out.telemetry):
+                    elog.emit("cluster_convergence", tile=t0, **rec)
+                elog.emit(
+                    "tile_done", tile=t0, res0=res0, res1=res1,
+                    mean_nu=mean_nu, diverged=bool(diverged),
+                    seconds=time.time() - tic,
+                    warm_start=warm_start, iterations=steps,
+                    phase_seconds=timer.tile_timings(),
+                )
+            results.append((res0, res1))
+            _ckpt_update(pi)
+        for k in ("tr_steps", "tcg_steps", "tr_capped", "lbfgs_iters"):
+            timer.note(k, steps[k])
+        jit_now = jit_clock()
+        timer.note("jit_s", jit_now[0] - jit_mark[0])
+        timer.note("jit_n", jit_now[1] - jit_mark[1])
+        jit_mark = jit_now
         log(
             f"tile {t0}: residual {res0:.6f} -> {res1:.6f} "
-            f"nu {float(out.mean_nu):.1f} ({time.time()-tic:.1f}s) "
+            f"nu {mean_nu:.1f} ({time.time()-tic:.1f}s) "
             f"[{timer.tile_summary()}]"
         )
-        results.append((res0, res1))
-        _ckpt_update(pi)
         note_activity("tile", name=f"tile{t0}", seconds=time.time() - tic)
-        tile_span.__exit__(None, None, None)
+        _end_tile()
 
     except ContractViolation as e:
         # SAGECAL_CHECKIFY contract tripped mid-solve: flush the
@@ -595,10 +604,9 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
     finally:
         # always reap the worker thread + its read handle, even when the
         # solve/write raises mid-loop; same for the transfer audit (its
-        # counts survive exit) and the XLA trace
+        # counts survive exit)
         prefetch_cm.__exit__(None, None, None)
         audit.__exit__(None, None, None)
-        trace_cm.__exit__(None, None, None)
         run_span.__exit__(None, None, None)
         close_tracer()  # writes trace.json alongside the span JSONL
     log(timer.run_summary())

@@ -55,22 +55,20 @@ def run_minibatch(cfg: RunConfig, log=print):
     """Epochs x minibatches over time, one solution per mini-band.
     Returns per-band final (res_0, res_1).
 
-    Thin exception-safe shell: the XLA trace (``SAGECAL_PROFILE_DIR``)
-    and the transfer audit (``SAGECAL_TRANSFER_AUDIT=1``) are opened
-    here so a crash mid-epoch still flushes a loadable trace and
-    restores stderr."""
+    Thin exception-safe shell: the transfer audit
+    (``SAGECAL_TRANSFER_AUDIT=1``) is opened here so a crash mid-epoch
+    still restores stderr."""
     from sagecal_tpu.obs.perf import (
         TransferAudit,
         enable_persistent_compilation_cache,
     )
-    from sagecal_tpu.utils.profiling import trace
 
     enable_persistent_compilation_cache()
     from sagecal_tpu.utils.platform import accelerator
 
     accelerator()  # no TPU and no explicit CPU choice: refuse to run
     audit = TransferAudit()
-    with trace(), audit:
+    with audit:
         return _run_minibatch(cfg, log, audit)
 
 

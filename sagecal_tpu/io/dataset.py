@@ -274,6 +274,10 @@ class TilePrefetcher:
 
     ``specs``: list of ``load_tile`` kwarg dicts — each yielded item
     carries one loaded VisData per spec, in order.
+
+    The worker times its read of each tile (a ``read`` profiler
+    annotation on its thread, and :meth:`read_seconds`), so the
+    consumer can tell a slow read from a stall.
     """
 
     _SENTINEL = object()
@@ -290,6 +294,7 @@ class TilePrefetcher:
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._started = False
+        self._read_s = {}
 
     def _worker(self):
         import jax
@@ -307,11 +312,14 @@ class TilePrefetcher:
                     # device HBM (up to current+queued+in-flight tiles
                     # coexist); the consumer's first jitted use moves
                     # them over
-                    with jax.default_device(cpu_device()):
+                    t_read = _time.perf_counter()
+                    with jax.default_device(cpu_device()), \
+                            jax.profiler.TraceAnnotation("read", tile=t0):
                         loads = tuple(
                             ds.load_tile(t0, self._tilesz, **spec)
                             for spec in self._specs
                         )
+                    self._read_s[t0] = _time.perf_counter() - t_read
                 except Exception as e:  # propagate into the consumer
                     self._q.put((t0, e))
                     return
@@ -392,6 +400,11 @@ class TilePrefetcher:
                     f"TilePrefetcher worker for {self._path!r} did not "
                     "exit within 5 s of context exit; it still holds an "
                     "open read handle", RuntimeWarning, stacklevel=2)
+
+    def read_seconds(self, t0) -> float:
+        """Seconds the worker took to read tile ``t0`` (0 when it is
+        not read yet); each tile's reading is handed out once."""
+        return self._read_s.pop(t0, 0.0)
 
     def __iter__(self):
         while True:

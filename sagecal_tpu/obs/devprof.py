@@ -7,12 +7,11 @@ dispatch floor, VMEM-ceiling splits) dominates the lost 99.86% of the
 0.14%-MFU headline.  This module is the hardware-truth half of PR 16:
 
 - **Capture** — :func:`start_device_profile` / :func:`stop_device_profile`
-  / :func:`device_profile` wrap ``jax.profiler.start_trace`` with the
-  same idempotent-owner discipline as ``utils/profiling.trace`` but a
-  separate opt-in (``SAGECAL_DEVICE_PROFILE=dir`` or the apps'
-  ``--device-profile`` flag), because this capture is consumed by our
-  own parser, not TensorBoard.  ``stop`` locates the newest emitted
-  ``*.trace.json(.gz)`` and remembers it for flight dumps.
+  / :func:`device_profile` wrap ``jax.profiler.start_trace`` with an
+  idempotent owner (``SAGECAL_DEVICE_PROFILE=dir`` or the apps'
+  ``--device-profile`` flag): the program's one capture path, read by
+  TensorBoard and by our own parser.  ``stop`` locates the newest
+  emitted ``*.trace.json(.gz)`` and remembers it for flight dumps.
 - **Fleet arming** — a coordinator drops an atomic JSON flag file in
   the fleet's shared out_dir (:func:`arm_fleet_profile`); the targeted
   worker's loop polls :func:`check_fleet_arm` and profiles exactly one
@@ -60,10 +59,7 @@ _last_trace: Optional[str] = None
 
 def start_device_profile(log_dir: Optional[str] = None) -> Optional[str]:
     """Begin a device-profile capture (idempotent).  Returns the capture
-    directory, or None when not requested.  Tolerates an already-active
-    profiler session (e.g. ``SAGECAL_PROFILE_DIR`` tracing is live):
-    jax allows one trace at a time, so we log-and-skip rather than
-    kill the run that asked for observability."""
+    directory, or None when not requested."""
     global _active_dir
     if _active_dir is not None:
         return _active_dir
@@ -73,16 +69,7 @@ def start_device_profile(log_dir: Optional[str] = None) -> Optional[str]:
     os.makedirs(log_dir, exist_ok=True)
     import jax
 
-    try:
-        jax.profiler.start_trace(log_dir)
-    except Exception as e:  # another trace already owns the profiler
-        try:
-            from sagecal_tpu.obs.flight import note_activity
-
-            note_activity(f"device_profile skipped: {e}")
-        except Exception:
-            pass
-        return None
+    jax.profiler.start_trace(log_dir)
     _active_dir = log_dir
     try:
         from sagecal_tpu.obs.flight import note_activity

@@ -188,6 +188,39 @@ def _install_cache_listener() -> None:
         pass
 
 
+# JAX's own compile work, from jax.monitoring durations: seconds of
+# jaxpr tracing, lowering and backend compilation (a persistent-cache
+# load included), and the count of backend compilations
+_JIT_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration",
+               "/jax/core/compile/backend_compile_duration")
+_JIT_CLOCK = {"s": 0.0, "n": 0}
+_jit_listener_installed = False
+
+
+def _jit_duration_listener(event: str, duration_secs: float, **_kw) -> None:
+    if event in _JIT_EVENTS:
+        with _LOCK:
+            _JIT_CLOCK["s"] += duration_secs
+            _JIT_CLOCK["n"] += event == _JIT_EVENTS[2]
+
+
+def jit_clock() -> Tuple[float, int]:
+    """(seconds, compilations) of JAX compile work in this process so
+    far: jaxpr tracing, lowering and backend compilation, cache loads
+    included.  The difference of two readings is what ran between them;
+    the apps print it per tile (``jit_s``, ``jit_n``)."""
+    global _jit_listener_installed
+    if not _jit_listener_installed:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _jit_duration_listener)
+        _jit_listener_installed = True
+    with _LOCK:
+        return _JIT_CLOCK["s"], _JIT_CLOCK["n"]
+
+
 def compile_cache_stats() -> Dict[str, int]:
     """Persistent-compilation-cache hit/miss counts observed so far."""
     with _LOCK:

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from flax import struct
 
 from sagecal_tpu.core.types import params_to_jones, jones_to_params
+from sagecal_tpu.obs.scopes import scoped
 from sagecal_tpu.utils.precision import true_f32
 
 
@@ -55,6 +56,29 @@ class RTRConfig:
     epsilon: float = struct.field(pytree_node=False, default=1e-12)
 
 
+class TRSteps(NamedTuple):
+    """Trust-region work of a solve, as int32 counts: the TR loops run,
+    their TR steps and tCG steps, and the loops that ended at their
+    iteration bound rather than at the gradient tolerance.  Always
+    returned (fixed shape), so the timed program reports its own work."""
+
+    loops: jax.Array
+    tr: jax.Array
+    tcg: jax.Array
+    capped: jax.Array
+
+
+def no_tr_steps() -> TRSteps:
+    """The counts of a solve that runs no trust-region loop."""
+    z = jnp.zeros((), jnp.int32)
+    return TRSteps(z, z, z, z)
+
+
+def sum_tr_steps(steps: TRSteps) -> TRSteps:
+    """Sum stacked counts over their leading axis."""
+    return TRSteps(*(jnp.sum(a, axis=0, dtype=jnp.int32) for a in steps))
+
+
 class RTRResult(NamedTuple):
     p: jax.Array  # (nchunk, 8N)
     cost0: jax.Array  # (nchunk,)
@@ -64,6 +88,8 @@ class RTRResult(NamedTuple):
     trace: Optional[tuple] = None
     # SolveQuality (ops.quality) when collect_quality=True, same contract
     quality: Optional[tuple] = None
+    # TRSteps summed over chunks (and robust rounds)
+    steps: Optional[TRSteps] = None
 
 
 # ---------------------------------------------------------------------------
@@ -200,6 +226,7 @@ def _station_iw(rowmask, ant_p, ant_q, N):
 # truncated CG (tcg_solve, rtr_solve.c:887-1080)
 # ---------------------------------------------------------------------------
 
+@scoped("tcg")
 def _tcg(x, grad, Delta, hess, cfg: RTRConfig):
     N = x.shape[0]
     zeros = jnp.zeros_like(x)
@@ -319,7 +346,8 @@ def _rtr_single(
         improved = (cost_c(x - beta * g) < fx) & (i < rsd_bound)
         return jnp.where(improved, x - beta * g, x), None
 
-    x, _ = jax.lax.scan(rsd_iter, x0, jnp.arange(cfg.itmax_rsd))
+    with jax.named_scope("rsd"):
+        x, _ = jax.lax.scan(rsd_iter, x0, jnp.arange(cfg.itmax_rsd))
 
     fx = cost_c(x)
     Delta_bar = jnp.minimum(fx, 0.01)
@@ -361,7 +389,7 @@ def _rtr_single(
         gnorm = jnp.sqrt(_g(g, g))
         st = dict(
             k=s["k"] + 1, x=x1, fx=fx1, Delta=Delta_new,
-            stop=gnorm < cfg.epsilon,
+            stop=gnorm < cfg.epsilon, cg=s["cg"] + cg_j.astype(jnp.int32),
         )
         if collect_trace:
             # ls_evals records the inner truncated-CG iteration count —
@@ -377,15 +405,20 @@ def _rtr_single(
 
     from sagecal_tpu.utils.platform import match_vma
 
-    state0 = dict(k=jnp.asarray(0), x=x, fx=fx, Delta=Delta0,
-                  stop=jnp.asarray(False))
+    state0 = dict(k=jnp.asarray(0, jnp.int32), x=x, fx=fx, Delta=Delta0,
+                  stop=jnp.asarray(False), cg=jnp.asarray(0, jnp.int32))
     if collect_trace:
         state0["trace"] = trace0
-    out = jax.lax.while_loop(tr_cond, tr_body, match_vma(state0, x))
+    with jax.named_scope("tr"):
+        out = jax.lax.while_loop(tr_cond, tr_body, match_vma(state0, x))
+    steps = TRSteps(loops=jnp.ones((), jnp.int32), tr=out["k"],
+                    tcg=out["cg"],
+                    capped=(~out["stop"]).astype(jnp.int32))
     # guard: never return something worse than the input
     better = out["fx"] <= fx0
     xf = jnp.where(better, out["x"], x0)
-    return xf, fx0, jnp.where(better, out["fx"], fx0), out.get("trace")
+    return (xf, fx0, jnp.where(better, out["fx"], fx0), out.get("trace"),
+            steps)
 
 
 def _nsd_single(
@@ -475,7 +508,8 @@ def _nsd_single(
         trace = None
     fx = cost_c(x)
     better = fx <= fx0
-    return jnp.where(better, x, x0), fx0, jnp.where(better, fx, fx0), trace
+    return (jnp.where(better, x, x0), fx0, jnp.where(better, fx, fx0), trace,
+            no_tr_steps())
 
 
 # ---------------------------------------------------------------------------
@@ -526,7 +560,8 @@ def _chunked(solver):
                     admm=(y_c, bz_c, r_c), **kwargs,
                 )
 
-            xf, c0, c1, tr = jax.vmap(lane)(jnp.arange(nchunk), x0, Yc, BZc, rho)
+            xf, c0, c1, tr, steps = jax.vmap(lane)(
+                jnp.arange(nchunk), x0, Yc, BZc, rho)
         else:
 
             def lane(c, x0_c):
@@ -535,12 +570,13 @@ def _chunked(solver):
                     vis, coh, rowmask, ant_p, ant_q, x0_c, *args, **kwargs
                 )
 
-            xf, c0, c1, tr = jax.vmap(lane)(jnp.arange(nchunk), x0)
+            xf, c0, c1, tr, steps = jax.vmap(lane)(jnp.arange(nchunk), x0)
         if tr is not None:
             # vmapped per-lane traces are (nchunk, itmax); present them
             # iteration-major like the LM trace: (itmax, nchunk)
             tr = jax.tree_util.tree_map(lambda a: jnp.moveaxis(a, 0, 1), tr)
-        return RTRResult(p=jones_to_params(xf), cost0=c0, cost=c1, trace=tr)
+        return RTRResult(p=jones_to_params(xf), cost0=c0, cost=c1, trace=tr,
+                         steps=sum_tr_steps(steps))
 
     return run
 
@@ -605,6 +641,7 @@ def nsd_solve(
     return out
 
 
+@scoped("nu")
 def _robust_weights_and_nu(
     vis, coh, mask, ant_p, ant_q, chunk_map, p, nu, nulow, nuhigh
 ):
@@ -667,7 +704,7 @@ def rtr_solve_robust(
             admm_y=admm_y, admm_bz=admm_bz, admm_rho=admm_rho,
             collect_trace=collect_trace,
         )
-        ys = (out.cost0, out.cost)
+        ys = (out.cost0, out.cost, out.steps)
         if collect_trace:
             tr = out.trace._replace(
                 nu=jnp.broadcast_to(nu1, out.trace.nu.shape).astype(
@@ -682,8 +719,8 @@ def rtr_solve_robust(
         em, match_vma((p0, jnp.asarray(nu0, p0.dtype)), p0), None,
         length=em_iters
     )
-    c0s, c1s = ys[0], ys[1]
-    trace = ys[2] if collect_trace else None  # (em_iters, itmax, nchunk)
+    c0s, c1s, steps = ys[0], ys[1], sum_tr_steps(ys[2])
+    trace = ys[3] if collect_trace else None  # (em_iters, itmax, nchunk)
     # re-estimate nu from the FINAL solution (the reference updates the
     # weights/nu once more after the loop, rtr_solve_robust.c:1625)
     sqrt_w_f, nu = _robust_weights_and_nu(
@@ -695,7 +732,7 @@ def rtr_solve_robust(
             p, vis, coh, mask, ant_p, ant_q, chunk_map,
             sqrt_w=sqrt_w_f, nu=nu)
     return RTRResult(p=p, cost0=c0s[0], cost=c1s[-1], trace=trace,
-                     quality=quality), nu
+                     quality=quality, steps=steps), nu
 
 
 @true_f32
@@ -728,7 +765,7 @@ def nsd_solve_robust(
             admm_y=admm_y, admm_bz=admm_bz, admm_rho=admm_rho,
             collect_trace=collect_trace,
         )
-        ys = (out.cost0, out.cost)
+        ys = (out.cost0, out.cost, out.steps)
         if collect_trace:
             tr = out.trace._replace(
                 nu=jnp.broadcast_to(nu1, out.trace.nu.shape).astype(
@@ -743,8 +780,8 @@ def nsd_solve_robust(
         em, match_vma((p0, jnp.asarray(nu0, p0.dtype)), p0), None,
         length=em_iters
     )
-    c0s, c1s = ys[0], ys[1]
-    trace = ys[2] if collect_trace else None  # (em_iters, itmax, nchunk)
+    c0s, c1s, steps = ys[0], ys[1], sum_tr_steps(ys[2])
+    trace = ys[3] if collect_trace else None  # (em_iters, itmax, nchunk)
     # final-solution nu re-estimate (rtr_solve_robust.c:2104)
     sqrt_w_f, nu = _robust_weights_and_nu(
         vis, coh, mask, ant_p, ant_q, chunk_map, p, nu, nulow, nuhigh
@@ -755,7 +792,7 @@ def nsd_solve_robust(
             p, vis, coh, mask, ant_p, ant_q, chunk_map,
             sqrt_w=sqrt_w_f, nu=nu)
     return RTRResult(p=p, cost0=c0s[0], cost=c1s[-1], trace=trace,
-                     quality=quality), nu
+                     quality=quality, steps=steps), nu
 
 
 # jitted module entries with compile/recompile telemetry (obs/perf.py)

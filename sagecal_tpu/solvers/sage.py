@@ -43,10 +43,12 @@ from flax import struct
 
 from sagecal_tpu.core.types import VisData, corrupt_flat, params_to_jones
 from sagecal_tpu.obs.perf import instrumented_jit
+from sagecal_tpu.obs.scopes import scoped
 from sagecal_tpu.ops.rime import SourceBatch, predict_coherencies
 from sagecal_tpu.solvers.lbfgs import lbfgs_fit
 from sagecal_tpu.solvers.lm import LMConfig, lm_solve, os_lm_solve
 from sagecal_tpu.solvers.robust import robust_lm_solve
+from sagecal_tpu.solvers.rtr import TRSteps, no_tr_steps, sum_tr_steps
 from sagecal_tpu.utils.precision import true_f32
 
 # solver modes (values match Dirac.h:1607-1613)
@@ -143,6 +145,11 @@ class SageResult(NamedTuple):
     # full residual at the returned p + gain health)} when
     # config.collect_quality, else None (same empty-pytree contract)
     quality: Optional[dict] = None
+    # int32 solver work of the solve, always returned: {"tr_loops",
+    # "tr_steps", "tcg_steps", "tr_capped"} summed over EM passes,
+    # clusters, robust rounds and chunks (rtr.TRSteps; zero for modes
+    # without a trust region), and "lbfgs_iters" of the joint LBFGS
+    steps: Optional[dict] = None
 
 
 def build_cluster_data(
@@ -445,11 +452,16 @@ def em_residual_scan(data: VisData, cdata: ClusterData, p_all, extras, solve_one
 
     def cluster_step(xres, inp):
         coh_k, cmap_k, p_k, extras_k = inp
-        model_old = cluster_model(p_k, coh_k, cmap_k, data.ant_p, data.ant_q)
-        xeff = xres + model_old
-        p_new, aux = solve_one(xeff, coh_k, cmap_k, p_k, extras_k)
-        model_new = cluster_model(p_new, coh_k, cmap_k, data.ant_p, data.ant_q)
-        return xeff - model_new, (p_new, aux)
+        with jax.named_scope("residual"):
+            model_old = cluster_model(p_k, coh_k, cmap_k, data.ant_p,
+                                      data.ant_q)
+            xeff = xres + model_old
+        with jax.named_scope("solve"):
+            p_new, aux = solve_one(xeff, coh_k, cmap_k, p_k, extras_k)
+        with jax.named_scope("residual"):
+            model_new = cluster_model(p_new, coh_k, cmap_k, data.ant_p,
+                                      data.ant_q)
+            return xeff - model_new, (p_new, aux)
 
     xres0 = data.vis - predict_full_model(p_all, cdata, data)
     xs = (cdata.coh, cdata.chunk_map, p_all, extras)
@@ -582,6 +594,7 @@ def _make_fused_joint_cost_batch(data, cdata, B, M, n8, robust, mean_nu_b,
     return cost_fn
 
 
+@scoped("em")
 def _em_phase(
     data: VisData,
     cdata: ClusterData,
@@ -591,10 +604,12 @@ def _em_phase(
 ):
     """The SAGE expectation passes of :func:`sagefit` — per-cluster
     solves and nu estimation, NO joint LBFGS and no finalization.
-    Returns ``(p, mean_nu, res_0, em_traces, em_quality)``.  Factored
-    out so :func:`sagefit_batched_fused` can vmap the per-cluster EM
-    machinery per lane while replacing the joint-LBFGS phase with one
-    batched fused kernel loop."""
+    Returns ``(p, mean_nu, res_0, em_traces, em_quality, em_steps)``,
+    ``em_steps`` the :class:`~sagecal_tpu.solvers.rtr.TRSteps` summed
+    over passes and clusters.  Factored out so
+    :func:`sagefit_batched_fused` can vmap the per-cluster EM machinery
+    per lane while replacing the joint-LBFGS phase with one batched
+    fused kernel loop."""
     M = cdata.coh.shape[0]
     F, rows = data.vis.shape[-3], data.vis.shape[-1]
     nreal = rows * F * 8
@@ -626,7 +641,9 @@ def _em_phase(
         want_q = collect_q and last_em
 
         def _aux_of(res, nu_k):
-            aux = (_nerr_of(res), nu_k)
+            steps = getattr(res, "steps", None)
+            aux = (_nerr_of(res), nu_k,
+                   no_tr_steps() if steps is None else steps)
             if collect:
                 aux = aux + (res.trace,)
             if want_q:
@@ -723,12 +740,13 @@ def _em_phase(
             data, cdata, p_all, (nerr, subkeys, nus_in), solve_one
         )
         nerr_new, nus = aux[0], aux[1]
-        tr = aux[2] if collect else None  # IterTrace, leading cluster axis
+        steps = sum_tr_steps(aux[2])
+        tr = aux[3] if collect else None  # IterTrace, leading cluster axis
         # SolveQuality with leading cluster axis on the final pass
         qual = aux[-1] if want_q else None
         total = jnp.sum(nerr_new)
         nerr_norm = jnp.where(total > 0.0, nerr_new / total, nerr_new)
-        return p_new, nerr_norm, nus, key, tr, qual
+        return p_new, nerr_norm, nus, key, tr, qual, steps
 
     p = p0
     nerr = jnp.zeros((M,), p0.dtype)
@@ -736,9 +754,11 @@ def _em_phase(
     nus = jnp.full((M,), config.nulow, p0.dtype)
     em_traces = []
     em_quality = None
+    em_steps = no_tr_steps()
     for em in range(config.max_emiter):
-        p, nerr, nus, key, tr, qual = em_iteration(
+        p, nerr, nus, key, tr, qual, steps = em_iteration(
             p, nerr, nus, weighted, em, key)
+        em_steps = TRSteps(*(a + b for a, b in zip(em_steps, steps)))
         if collect:
             em_traces.append(tr)
         if qual is not None:
@@ -746,9 +766,10 @@ def _em_phase(
         if config.randomize:
             weighted = ~weighted
     mean_nu = jnp.clip(jnp.mean(nus), config.nulow, config.nuhigh)
-    return p, mean_nu, res_0, em_traces, em_quality
+    return p, mean_nu, res_0, em_traces, em_quality, em_steps
 
 
+@scoped("finalize")
 def _finalize(
     data: VisData,
     cdata: ClusterData,
@@ -759,6 +780,8 @@ def _finalize(
     lbfgs_trace,
     em_traces,
     em_quality,
+    em_steps,
+    lbfgs_iters,
 ) -> SageResult:
     """Final full-model residual plus telemetry/quality bundling — the
     tail of :func:`sagefit` after the joint LBFGS, shared with the
@@ -802,9 +825,13 @@ def _finalize(
             nu=mean_nu if robust else None,
         )
         quality = {"em": em_quality, "final": final_q}
+    steps = {"tr_loops": em_steps.loops, "tr_steps": em_steps.tr,
+             "tcg_steps": em_steps.tcg, "tr_capped": em_steps.capped,
+             "lbfgs_iters": jnp.asarray(lbfgs_iters, jnp.int32)}
     return SageResult(
         p=p, res_0=res_0, res_1=res_1, mean_nu=mean_nu,
         diverged=res_1 > res_0, telemetry=telemetry, quality=quality,
+        steps=steps,
     )
 
 
@@ -819,16 +846,22 @@ def sagefit(
     """One tile's SAGE calibration.  ``p0``: (M, nchunk_max, 8N)."""
     if key is None:
         key = jax.random.PRNGKey(0)
-    M = cdata.coh.shape[0]
-    nchunk_max = p0.shape[1]
-    n8 = p0.shape[2]
+    p, mean_nu, res_0, em_traces, em_quality, em_steps = _em_phase(
+        data, cdata, p0, config, key)
+    with jax.named_scope("lbfgs"):
+        p, lbfgs_trace, lbfgs_iters = _joint_lbfgs(
+            data, cdata, p, mean_nu, config)
+    return _finalize(data, cdata, p, res_0, mean_nu, config, lbfgs_trace,
+                     em_traces, em_quality, em_steps, lbfgs_iters)
+
+
+def _joint_lbfgs(data, cdata, p, mean_nu, config):
+    """The joint LBFGS over all parameters (lmfit.c:1019-1037) of
+    :func:`sagefit`.  Returns ``(p, lbfgs_trace, iterations)``."""
+    M, nchunk_max, n8 = p.shape
     robust = config.solver_mode in _ROBUST_MODES
     collect = config.collect_telemetry
-
-    p, mean_nu, res_0, em_traces, em_quality = _em_phase(
-        data, cdata, p0, config, key)
-
-    # ---- joint LBFGS over all parameters (lmfit.c:1019-1037) ----
+    lbfgs_trace, iters = None, 0
     if config.max_lbfgs > 0:
         pflat0 = p.reshape(-1)
 
@@ -857,19 +890,15 @@ def sagefit(
                 itmax=config.max_lbfgs, M=config.lbfgs_m,
             )
             p = fitb.p.reshape(M, nchunk_max, n8)
-            lbfgs_trace = None  # bounded path not instrumented
+            iters = fitb.iterations  # no trace on the bounded path
         else:
             fit = lbfgs_fit(
                 cost_fn, None, pflat0, itmax=config.max_lbfgs,
                 M=config.lbfgs_m, collect_trace=collect,
             )
             p = fit.p.reshape(M, nchunk_max, n8)
-            lbfgs_trace = fit.trace
-    else:
-        lbfgs_trace = None
-
-    return _finalize(data, cdata, p, res_0, mean_nu, config, lbfgs_trace,
-                     em_traces, em_quality)
+            lbfgs_trace, iters = fit.trace, fit.iterations
+    return p, lbfgs_trace, iters
 
 
 @true_f32
@@ -921,27 +950,30 @@ def sagefit_batched_fused(
 
     # quality side outputs (collect_quality) vmap straight through —
     # only telemetry traces are excluded (guarded above)
-    p_b, mean_nu_b, res_0_b, _, em_q = jax.vmap(
+    p_b, mean_nu_b, res_0_b, _, em_q, em_steps_b = jax.vmap(
         lambda d, c, p, k: _em_phase(d, c, p, config, k)
     )(data, cdata, p0, keys)
 
+    iters_b = jnp.zeros((B,), jnp.int32)
     if config.max_lbfgs > 0:
         from sagecal_tpu.solvers.lbfgs import lbfgs_fit_batched
 
-        cost_fn = _make_fused_joint_cost_batch(
-            data, cdata, B, M, n8, robust, mean_nu_b, config.coh_dtype,
-            valid,
-        )
-        fit = lbfgs_fit_batched(
-            cost_fn, p_b.reshape(B, -1), itmax=config.max_lbfgs,
-            M=config.lbfgs_m,
-        )
+        with jax.named_scope("lbfgs"):
+            cost_fn = _make_fused_joint_cost_batch(
+                data, cdata, B, M, n8, robust, mean_nu_b, config.coh_dtype,
+                valid,
+            )
+            fit = lbfgs_fit_batched(
+                cost_fn, p_b.reshape(B, -1), itmax=config.max_lbfgs,
+                M=config.lbfgs_m,
+            )
         p_b = fit.p.reshape(B, M, nchunk_max, n8)
+        iters_b = fit.iterations
 
     return jax.vmap(
-        lambda d, c, p, r0, mn, eq: _finalize(d, c, p, r0, mn, config,
-                                              None, [], eq)
-    )(data, cdata, p_b, res_0_b, mean_nu_b, em_q)
+        lambda d, c, p, r0, mn, eq, st, it: _finalize(
+            d, c, p, r0, mn, config, None, [], eq, st, it)
+    )(data, cdata, p_b, res_0_b, mean_nu_b, em_q, em_steps_b, iters_b)
 
 
 # ------------------------------------------------ packed device boundary

@@ -221,7 +221,8 @@ class TestSolverTraces:
             lambda p: rtr_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg)
         )(p0)
         assert off.trace is None
-        assert len(jax.tree_util.tree_leaves(off)) == 3
+        # p, cost0, cost and the four TRSteps counts
+        assert len(jax.tree_util.tree_leaves(off)) == 7
         on = jax.jit(
             lambda p: rtr_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg,
                                 collect_trace=True)
@@ -537,7 +538,8 @@ class TestSagefitTelemetry:
         off = sagefit(obs, cdata, p0,
                       SageConfig(max_emiter=2, max_iter=4, max_lbfgs=4))
         assert off.telemetry is None
-        assert len(jax.tree_util.tree_leaves(off)) == 5
+        # p, res_0, res_1, mean_nu, diverged and the five step counts
+        assert len(jax.tree_util.tree_leaves(off)) == 10
 
         on = sagefit(obs, cdata, p0,
                      SageConfig(max_emiter=2, max_iter=4, max_lbfgs=4,

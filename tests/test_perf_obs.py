@@ -433,27 +433,8 @@ class TestGate:
 
 
 # ---------------------------------------------------------------------------
-# profiling satellites: trace context manager + chunk-plan contract
+# profiling satellites: chunk-plan contract
 # ---------------------------------------------------------------------------
-
-
-class TestTraceCM:
-    def test_noop_without_dir(self, monkeypatch):
-        from sagecal_tpu.utils import profiling
-
-        monkeypatch.delenv("SAGECAL_PROFILE_DIR", raising=False)
-        with profiling.trace() as d:
-            assert d is None
-
-    def test_trace_stops_on_exception(self, tmp_path, monkeypatch):
-        from sagecal_tpu.utils import profiling
-
-        with pytest.raises(RuntimeError):
-            with profiling.trace(str(tmp_path / "tr")):
-                jnp.arange(4.0).block_until_ready()
-                raise RuntimeError("boom")
-        # the finally released the trace: a fresh one can start
-        assert profiling._active_trace is None
 
 
 class TestChunkPlanContract:

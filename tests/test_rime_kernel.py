@@ -944,6 +944,13 @@ def test_batched_fused_solve_matches_vmapped_xla(mode):
                                  keys)
     assert float(jnp.max(jnp.abs(out_f.p - out_x.p))) <= 1e-4
     assert float(jnp.max(jnp.abs(out_f.res_1 - out_x.res_1))) <= 1e-5
+    # both routes count each lane's solver steps (LM modes: no TR loops)
+    for out in (out_f, out_x):
+        assert {k: v.shape for k, v in out.steps.items()} == {
+            k: (B,) for k in out.steps}
+        assert int(jnp.sum(out.steps["tr_loops"])) == 0
+        it = out.steps["lbfgs_iters"]
+        assert bool(jnp.all((it > 0) & (it <= cfg.max_lbfgs)))
 
 
 def test_batched_solve_donated_bit_identical_and_consumes_input():
