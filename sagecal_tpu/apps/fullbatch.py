@@ -576,7 +576,8 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                 )
             results.append((res0, res1))
             _ckpt_update(pi)
-        for k in ("tr_steps", "tcg_steps", "tr_capped", "lbfgs_iters"):
+        for k in ("tr_steps", "tcg_steps", "tr_capped", "rsd_steps",
+                  "rsd_moved", "lbfgs_iters"):
             timer.note(k, steps[k])
         jit_now = jit_clock()
         timer.note("jit_s", jit_now[0] - jit_mark[0])

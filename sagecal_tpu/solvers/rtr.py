@@ -58,20 +58,24 @@ class RTRConfig:
 
 class TRSteps(NamedTuple):
     """Trust-region work of a solve, as int32 counts: the TR loops run,
-    their TR steps and tCG steps, and the loops that ended at their
-    iteration bound rather than at the gradient tolerance.  Always
-    returned (fixed shape), so the timed program reports its own work."""
+    their TR steps and tCG steps, the loops that ended at their
+    iteration bound rather than at the gradient tolerance, and the RSD
+    warm-up iterations run ahead of them and those that moved x.
+    Always returned (fixed shape), so the timed program reports its own
+    work."""
 
     loops: jax.Array
     tr: jax.Array
     tcg: jax.Array
     capped: jax.Array
+    rsd: jax.Array
+    rsd_moved: jax.Array
 
 
 def no_tr_steps() -> TRSteps:
     """The counts of a solve that runs no trust-region loop."""
     z = jnp.zeros((), jnp.int32)
-    return TRSteps(z, z, z, z)
+    return TRSteps(z, z, z, z, z, z)
 
 
 def sum_tr_steps(steps: TRSteps) -> TRSteps:
@@ -328,28 +332,42 @@ def _rtr_single(
     fx0 = cost_c(x0)
 
     # ---- RSD warmup with Armijo backtracking (armijostep) -------------
-    def rsd_iter(x, i):
+    # A rejected step leaves x unchanged, and with it every later
+    # iteration: the loop ends there, or at the dynamic bound.  Each
+    # trial cost is evaluated once and the accepted one carried as fx.
+    def rsd_cond(s):
+        i, _, _, moved = s
+        return (i < rsd_bound) & moved
+
+    def rsd_body(s):
+        i, x, fx, _ = s
         g = grad_fn(x, iw)
-        fx = cost_c(x)
         gg = _g(g, g)
-        beta0 = jnp.asarray(1.0, gg.dtype)
 
         def armijo_cond(st):
-            k, beta = st
-            return (k < 12) & (cost_c(x - beta * g) > fx - 1e-4 * beta * gg)
+            k, beta, f_try = st
+            return (k < 12) & (f_try > fx - 1e-4 * beta * gg)
 
         def armijo_body(st):
-            k, beta = st
-            return k + 1, beta * 0.5
+            k, beta, _ = st
+            beta = beta * 0.5
+            return k + 1, beta, cost_c(x - beta * g)
 
-        k, beta = jax.lax.while_loop(armijo_cond, armijo_body, (0, beta0))
-        improved = (cost_c(x - beta * g) < fx) & (i < rsd_bound)
-        return jnp.where(improved, x - beta * g, x), None
+        _, beta, f_try = jax.lax.while_loop(
+            armijo_cond, armijo_body,
+            (0, jnp.asarray(1.0, gg.dtype), cost_c(x - g)))
+        improved = f_try < fx
+        return (i + 1, jnp.where(improved, x - beta * g, x),
+                jnp.where(improved, f_try, fx), improved)
+
+    from sagecal_tpu.utils.platform import match_vma
 
     with jax.named_scope("rsd"):
-        x, _ = jax.lax.scan(rsd_iter, x0, jnp.arange(cfg.itmax_rsd))
+        n_rsd, x, fx, moved = jax.lax.while_loop(
+            rsd_cond, rsd_body,
+            match_vma((jnp.asarray(0, jnp.int32), x0, fx0,
+                       jnp.asarray(True)), x0))
 
-    fx = cost_c(x)
     Delta_bar = jnp.minimum(fx, 0.01)
     Delta0 = Delta_bar * 0.125
     rho_reg0 = fx * 1e-6
@@ -403,8 +421,6 @@ def _rtr_single(
             )
         return st
 
-    from sagecal_tpu.utils.platform import match_vma
-
     state0 = dict(k=jnp.asarray(0, jnp.int32), x=x, fx=fx, Delta=Delta0,
                   stop=jnp.asarray(False), cg=jnp.asarray(0, jnp.int32))
     if collect_trace:
@@ -413,7 +429,8 @@ def _rtr_single(
         out = jax.lax.while_loop(tr_cond, tr_body, match_vma(state0, x))
     steps = TRSteps(loops=jnp.ones((), jnp.int32), tr=out["k"],
                     tcg=out["cg"],
-                    capped=(~out["stop"]).astype(jnp.int32))
+                    capped=(~out["stop"]).astype(jnp.int32),
+                    rsd=n_rsd, rsd_moved=n_rsd - (~moved).astype(jnp.int32))
     # guard: never return something worse than the input
     better = out["fx"] <= fx0
     xf = jnp.where(better, out["x"], x0)

@@ -146,9 +146,10 @@ class SageResult(NamedTuple):
     # config.collect_quality, else None (same empty-pytree contract)
     quality: Optional[dict] = None
     # int32 solver work of the solve, always returned: {"tr_loops",
-    # "tr_steps", "tcg_steps", "tr_capped"} summed over EM passes,
-    # clusters, robust rounds and chunks (rtr.TRSteps; zero for modes
-    # without a trust region), and "lbfgs_iters" of the joint LBFGS
+    # "tr_steps", "tcg_steps", "tr_capped", "rsd_steps", "rsd_moved"}
+    # summed over EM passes, clusters, robust rounds and chunks
+    # (rtr.TRSteps; zero for modes without a trust region), and
+    # "lbfgs_iters" of the joint LBFGS
     steps: Optional[dict] = None
 
 
@@ -827,6 +828,7 @@ def _finalize(
         quality = {"em": em_quality, "final": final_q}
     steps = {"tr_loops": em_steps.loops, "tr_steps": em_steps.tr,
              "tcg_steps": em_steps.tcg, "tr_capped": em_steps.capped,
+             "rsd_steps": em_steps.rsd, "rsd_moved": em_steps.rsd_moved,
              "lbfgs_iters": jnp.asarray(lbfgs_iters, jnp.int32)}
     return SageResult(
         p=p, res_0=res_0, res_1=res_1, mean_nu=mean_nu,

@@ -221,8 +221,8 @@ class TestSolverTraces:
             lambda p: rtr_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg)
         )(p0)
         assert off.trace is None
-        # p, cost0, cost and the four TRSteps counts
-        assert len(jax.tree_util.tree_leaves(off)) == 7
+        # p, cost0, cost and the six TRSteps counts
+        assert len(jax.tree_util.tree_leaves(off)) == 9
         on = jax.jit(
             lambda p: rtr_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg,
                                 collect_trace=True)

@@ -232,6 +232,11 @@ def test_step_counts_match_the_telemetry_traces(tile):
     assert steps["tr_loops"] == cfg.max_emiter * NCLUS * \
         cfg.em_rounds_robust
     assert 0 <= steps["tr_capped"] <= steps["tr_loops"]
+    # the warm-up ahead of each TR loop runs at least one iteration and
+    # at most its static ceiling, and moves x on some of them
+    itmax_rsd = cfg.max_iter * cfg.iter_budget_cap + 5
+    assert 0 < steps["rsd_steps"] <= steps["tr_loops"] * itmax_rsd
+    assert 0 <= steps["rsd_moved"] <= steps["rsd_steps"]
     lb = np.asarray(res.telemetry["lbfgs"].cost)
     assert steps["lbfgs_iters"] == int(np.isfinite(lb).sum()) > 0
 
@@ -246,6 +251,7 @@ def test_modes_without_a_trust_region_count_zero(tile):
         data, cdata, jnp.asarray(p0), config=cfg)
     assert {k: int(v) for k, v in res.steps.items()} == {
         "tr_loops": 0, "tr_steps": 0, "tcg_steps": 0, "tr_capped": 0,
+        "rsd_steps": 0, "rsd_moved": 0,
         "lbfgs_iters": int(res.steps["lbfgs_iters"])}
     assert 0 < int(res.steps["lbfgs_iters"]) <= 2
 
@@ -382,6 +388,7 @@ def test_tile_line_phases_cover_the_tile_period(tmp_path):
         assert covered + 0.005 * len(top) >= 0.95 * (t - t_prev), line
         prepared = ("load", "coh", "read_s") if "load+coh" in vals else ()
         for k in prepared + ("tr_steps", "tcg_steps", "tr_capped",
-                             "lbfgs_iters", "jit_s", "jit_n"):
+                             "rsd_steps", "rsd_moved", "lbfgs_iters",
+                             "jit_s", "jit_n"):
             assert k in vals, (k, line)
         assert int(vals["tr_steps"]) > 0
