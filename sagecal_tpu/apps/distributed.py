@@ -26,24 +26,26 @@ from typing import List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sagecal_tpu.apps.config import RunConfig
 from sagecal_tpu.core.types import (
     identity_jones,
     jones_to_params,
-    mat_of_flat,
     params_to_jones,
 )
 from sagecal_tpu.io import solutions as solio
 from sagecal_tpu.io.dataset import TilePrefetcher, VisDataset
 from sagecal_tpu.io.skymodel import load_sky, read_cluster_rho
-from sagecal_tpu.ops.residual import calculate_residuals
+from sagecal_tpu.ops.rime import ST_POINT, ST_SHAPELET
 from sagecal_tpu.parallel import consensus
 from sagecal_tpu.parallel.mesh import (
     SpatialConfig,
     make_admm_mesh_fn,
+    make_band_prep_fn,
+    make_band_residual_fn,
     stack_for_mesh,
+    stack_sources,
 )
 from sagecal_tpu.solvers.lm import LMConfig
 from sagecal_tpu.solvers.sage import build_cluster_data
@@ -89,7 +91,9 @@ def append_global_z(fh, Z, nstations, npoly, nchunk_max, flush: bool = True):
 
 def _check_band_consistency(metas, log):
     """The master's metadata validation (sagecal_master.cpp:238-287):
-    all bands must agree on N / nbase / timeslot count."""
+    all bands must agree on N / nbase / timeslot count, and on the
+    channel count (the bands' tiles are stacked at full channel
+    resolution)."""
     n0, nb0, nt0 = metas[0].nstations, metas[0].nbase, metas[0].ntime
     for i, m in enumerate(metas[1:], 1):
         if (m.nstations, m.nbase) != (n0, nb0):
@@ -97,10 +101,34 @@ def _check_band_consistency(metas, log):
                 f"band {i}: station/baseline layout mismatch "
                 f"({m.nstations},{m.nbase}) != ({n0},{nb0})"
             )
+        if m.nchan != metas[0].nchan:
+            raise ValueError(
+                f"band {i}: {m.nchan} channels != {metas[0].nchan}")
         if m.ntime != nt0:
             log(f"warning: band {i} has {m.ntime} timeslots != {nt0}; "
                 f"using the minimum")
     return min(m.ntime for m in metas)
+
+
+def read_band_solutions(prefix, nbands, M, nchunk_max, N):
+    """The last interval of each band's solution file
+    ``{prefix}.band{i}`` (the files ``-p prefix`` writes) as
+    (nbands, M, nchunk_max, 8N) parameters: the ``-q`` warm start of a
+    multi-band run."""
+    from sagecal_tpu.utils.platform import cpu_device
+
+    out = []
+    for i in range(nbands):
+        path = f"{prefix}.band{i}"
+        _, js = solio.read_solutions(path)
+        if js.shape[1:3] != (M * nchunk_max, N):
+            raise ValueError(
+                f"{path}: {js.shape[1]} clusters x {js.shape[2]} stations, "
+                f"expected {M * nchunk_max} x {N}")
+        with jax.default_device(cpu_device()):
+            out.append(np.asarray(jones_to_params(js[-1])).reshape(
+                M, nchunk_max, 8 * N))
+    return np.stack(out)
 
 
 def _emit_admm_attribution(tracer, elog, log, t0, admm_seconds,
@@ -538,17 +566,26 @@ def _run_distributed_inner(
         ]
         if "zdiff" in resume_state:
             zdiff_carry = jnp.asarray(resume_state["zdiff"], dtype)
+    elif cfg.init_solutions:
+        # -q: each band starts from its own solution file, as -p writes
+        # them; zero-weight padding bands keep the identity
+        p0 = np.array(p_bands)
+        p0[:Nf] = read_band_solutions(cfg.init_solutions, Nf, M,
+                                      nchunk_max, N)
+        p_bands = jnp.asarray(p0, dtype)
     tile_starts = list(range(0, ntime, cfg.tilesz))
     pairs = [(i, t0) for i, t0 in enumerate(tile_starts)
              if i >= cfg.skip_tiles]
     if cfg.max_tiles:
         pairs = pairs[: cfg.max_tiles]
     pairs = pairs[resume_done:]
-    # Per-band background prefetch of the FULL-SIZE tiles (the final
-    # clamped partial tile loads directly): each band's next tile reads
-    # while the mesh ADMM solves the current one (TilePrefetcher,
-    # io/dataset.py — the fullbatch loop's loadData-overlap role).
-    spec = [dict(average_channels=True, min_uvcut=cfg.min_uvcut,
+    # Per-band background prefetch of the FULL-SIZE tiles at full
+    # channel resolution (the final clamped partial tile loads
+    # directly): each band's next tile reads while the mesh ADMM solves
+    # the current one (TilePrefetcher, io/dataset.py — the fullbatch
+    # loop's loadData-overlap role).  The solve's channel average is
+    # made on the chip (make_band_prep_fn).
+    spec = [dict(average_channels=False, min_uvcut=cfg.min_uvcut,
                  max_uvcut=cfg.max_uvcut, dtype=dtype,
                  column=cfg.in_column)]
     full_t0s = [t0 for _, t0 in pairs
@@ -557,28 +594,56 @@ def _run_distributed_inner(
         TilePrefetcher(path, full_t0s, spec, cfg.tilesz, depth=1)
         for path in datasets
     ]
-    from sagecal_tpu.obs.perf import TransferAudit, emit_perf_events
+    from sagecal_tpu.obs.perf import (
+        TransferAudit, emit_perf_events, jit_clock,
+    )
+    from sagecal_tpu.utils.platform import cpu_device
     from sagecal_tpu.utils.profiling import PhaseTimer
 
     timer = PhaseTimer()
     audit = TransferAudit()
     audit.__enter__()
 
-    def _prepare_tile(t0, zdiff):
-        """Load + precompute one tile's per-band arrays.  All device
-        work here is ASYNC-dispatched jit (JAX returns before compute
-        finishes), so calling this between dispatching tile t's solve
-        and blocking on its outputs overlaps the coherency precompute
-        with the device solve — the role of the reference's per-tile
-        threaded precalculate_coherencies (fullbatch_mode.cpp:371-388)
-        without a host thread pool.  ``zdiff`` may be a LAZY device
-        array from the in-flight solve (the diffuse chain stays on
-        device, no sync)."""
-        datas, cdatas, fratios = [], [], []
+    # The band pipeline on the mesh: each device averages, predicts and
+    # subtracts its own bands (parallel/mesh.py band programs).  Skies
+    # whose coherencies need more than the point/extended predict keep a
+    # band-by-band prediction on the host's dispatch.
+    stypes = np.concatenate([np.asarray(c.stype) for c in clusters])
+    per_band = None
+    if diffuse_idx is not None:
+        per_band = ("the diffuse cluster is re-predicted from the "
+                    "spatial model")
+    elif np.any(stypes == ST_SHAPELET):
+        per_band = "shapelet sources need the mode table"
+    if per_band:
+        log(f"distributed: coherencies predicted band by band ({per_band})")
+    nchan = meta0.nchan
+    fdelta_chan = meta0.deltaf / max(nchan, 1)
+    band_sh = NamedSharding(mesh, P("freq"))
+    repl = NamedSharding(mesh, P())
+    prep_fn = make_band_prep_fn(
+        mesh, nchunks, fdelta_chan, with_coherencies=per_band is None,
+        has_extended=bool(np.any(stypes != ST_POINT)))
+    residual_fn = make_band_residual_fn(mesh,
+                                        global_solution=global_residual)
+    sources = (None if per_band else
+               jax.device_put(stack_sources(clusters), repl))
+    rho_m_dev = jax.device_put(np.asarray(rho_m, dtype), repl)
+    B_dev = jax.device_put(np.asarray(B_pad, dtype), band_sh)
+    # the warm start sharded as every later tile's (the previous out.p),
+    # so the first dispatch compiles the program the later ones run
+    p_bands = jax.device_put(p_bands, band_sh)
+    f0_bands = np.asarray([m.freq0 for m in metas]
+                          + [meta0.freq0] * (Nf_pad - Nf), dtype)
+
+    def _load(t0):
+        """This tile of every band at full channel resolution, stacked
+        on the host; zero-weight padding bands repeat band 0, masked."""
         # clamp the tile to the COMMON timeslot range so bands with more
         # timeslots than ntime_min still produce equal row counts on the
-        # final partial tile (stack_for_mesh needs identical shapes)
+        # final partial tile
         eff_tilesz = min(cfg.tilesz, ntime - t0)
+        tiles = []
         for bi, h in enumerate(handles):
             if eff_tilesz == cfg.tilesz:
                 t0_chk, (d,) = next(pf_iters[bi])
@@ -587,17 +652,37 @@ def _run_distributed_inner(
                         f"band {bi} prefetch order mismatch: "
                         f"{t0_chk} != {t0}"
                     )
+                timer.note("read_s", prefetchers[bi].read_seconds(t0))
             else:
                 # same kwargs as the prefetch spec so the two load
                 # paths can never drift apart
-                d = h.load_tile(t0, eff_tilesz, **spec[0])
-            # static pytree fields must match across the stacked bands
-            # (the per-channel ``freqs`` array carries each band's true
-            # frequency; freq0/deltaf statics only matter pre-stack)
-            d = d.replace(freq0=freq0, deltaf=meta0.deltaf)
-            datas.append(d)
-            cdata_b = build_cluster_data(d, clusters, nchunks,
-                                         shapelets=shapelets)
+                with jax.default_device(cpu_device()):
+                    d = h.load_tile(t0, eff_tilesz, **spec[0])
+            tiles.append(d)
+
+        def stack(name):
+            xs = [np.asarray(getattr(d, name)) for d in tiles]
+            return np.stack(xs + xs[:1] * (Nf_pad - Nf))
+
+        full = tiles[0].replace(
+            **{k: stack(k) for k in ("u", "v", "w", "ant_p", "ant_q",
+                                     "vis", "mask", "freqs", "time_idx")},
+            freq0=freq0, deltaf=meta0.deltaf)
+        full.mask[Nf:] = 0
+        return full
+
+    def _coherencies_per_band(full_host, data, zdiff):
+        """Band-by-band coherencies (build_cluster_data) for the skies
+        the band program does not predict."""
+        data_host = jax.device_get(data)
+        cdatas, cchans = [], []
+        for bi in range(Nf):
+            d_avg = jax.tree_util.tree_map(lambda x: x[bi], data_host)
+            d_full = jax.tree_util.tree_map(lambda x: x[bi], full_host)
+            c = build_cluster_data(d_avg, clusters, nchunks,
+                                   shapelets=shapelets)
+            cc = build_cluster_data(d_full, clusters, nchunks,
+                                    fdelta=fdelta_chan, shapelets=shapelets)
             if diffuse_idx is not None and zdiff is not None:
                 # re-predict the diffuse cluster from the previous
                 # tile's diffuse-constrained spatial model
@@ -609,23 +694,58 @@ def _run_distributed_inner(
                 from sagecal_tpu.parallel.spatial import bz_spatial
 
                 Zb = bz_spatial(zdiff, B_pad[bi], N)
-                cdata_b = recalculate_diffuse_coherencies(
-                    d, cdata_b, diffuse_idx, clusters[diffuse_idx],
+                c = recalculate_diffuse_coherencies(
+                    d_avg, c, diffuse_idx, clusters[diffuse_idx],
                     shapelets, Zb, spatial_n0, diffuse_beta,
                 )
-            cdatas.append(cdata_b)
-            # LAZY unflagged fraction: a host float() here would block
-            # behind the in-flight tile-t solve on an in-order device
-            # stream, serializing 'prepare' after the solve; the sync
-            # happens at the NEXT dispatch when the queue is free
-            fratios.append(jnp.mean(d.mask))
-        # zero-weight padding bands: replicate band 0 with mask 0
-        for _ in range(Nf_pad - Nf):
-            dpad = datas[0].replace(mask=jnp.zeros_like(datas[0].mask))
-            datas.append(dpad)
-            cdatas.append(cdatas[0])
-            fratios.append(jnp.zeros(()))
-        return datas, cdatas, fratios
+                cc = recalculate_diffuse_coherencies(
+                    d_full, cc, diffuse_idx, clusters[diffuse_idx],
+                    shapelets, Zb, spatial_n0, diffuse_beta,
+                    fdelta=fdelta_chan,
+                )
+            cdatas.append(c)
+            cchans.append(cc.coh)
+        # zero-weight padding bands take band 0's coherencies
+        cdatas += cdatas[:1] * (Nf_pad - Nf)
+        cchans += cchans[:1] * (Nf_pad - Nf)
+        return stack_for_mesh(cdatas), jnp.stack(cchans)
+
+    def _prepare(t0, zdiff):
+        """Load one tile and dispatch its band program.  All device work
+        here is ASYNC, so calling this between dispatching tile t's
+        solve and blocking on it overlaps the next tile's read, channel
+        average and coherencies with the device solve — the role of
+        the reference's per-tile threaded precalculate_coherencies
+        (fullbatch_mode.cpp:371-388).  ``zdiff`` may be a LAZY device
+        array from the in-flight solve (the diffuse chain stays on
+        device, no sync)."""
+        with timer.phase("load"):
+            full_host = _load(t0)
+        with timer.phase("coh"):
+            full, f0b = jax.device_put((full_host, f0_bands), band_sh)
+            data, cdata, coh_chan, rho, fratio = prep_fn(
+                full, f0b, sources, rho_m_dev)
+            if per_band:
+                cdata, coh_chan = _coherencies_per_band(full_host, data,
+                                                        zdiff)
+        return dict(full=full, data=data, cdata=cdata, coh_chan=coh_chan,
+                    rho=rho, fratio=fratio)
+
+    def _dispatch(prep, p_start):
+        """Dispatch one tile's mesh ADMM (async)."""
+        nonlocal fn
+        if fn is None:
+            # first tile: build the rebalanced fine-grained program on
+            # this tile's unflagged-row fractions (padded bands get
+            # zero weight -> their slots stop billing rounds)
+            bw = np.zeros((Nf_pad,))
+            bw[:Nf] = np.asarray(prep["fratio"])[:Nf]
+            fn = _build_mesh_fn(band_weights=bw)
+        t_unix, t_perf = time.time(), time.perf_counter()
+        with timer.phase("solve"):
+            out = fn(prep["data"], prep["cdata"], p_start, prep["rho"],
+                     B_dev)
+        return out, t_unix, t_perf
 
     pf_iters = []
 
@@ -651,39 +771,22 @@ def _run_distributed_inner(
     run_span = tracer.span("distributed", kind="run", bands=Nf, ndev=ndev,
                            nadmm=nadmm)
     run_span.__enter__()
+    inflight = None
     try:
       pf_iters = [iter(pf.__enter__()) for pf in prefetchers]
-      prepared = None
+      jit_mark = jit_clock()
       if pairs:
-        with timer.phase("prepare"):
-            prepared = _prepare_tile(pairs[0][1], zdiff_carry)
+          prepared = _prepare(pairs[0][1], zdiff_carry)
+          inflight = _dispatch(prepared, p_bands)
+      # The tile pipeline: tile t's residuals are dispatched before tile
+      # t+1's solve, and fetched and written while it runs.
       for pi, (tile_no, t0) in enumerate(pairs):
         tic = time.time()
         tile_span = tracer.span("tile", kind="tile", tile=t0)
         tile_span.__enter__()
-        datas, cdatas, fratios_lazy = prepared
-        # sync the lazy per-band unflagged fractions NOW (the previous
-        # tile's solve has been consumed, the queue is free)
-        fratios = [float(np.asarray(f)) for f in fratios_lazy]
-        # rho scaled by each band's unflagged fraction (master :709-723)
-        rho = jnp.asarray(
-            np.asarray(fratios)[:, None] * rho_m[None, :], dtype
-        )
-        if fn is None:
-            # first tile: build the rebalanced fine-grained program on
-            # this tile's unflagged-row fractions (padded bands get
-            # zero weight -> their slots stop billing rounds)
-            bw = np.zeros((Nf_pad,))
-            bw[:Nf] = np.asarray(fratios[:Nf])
-            fn = _build_mesh_fn(band_weights=bw)
-        admm_start_unix = time.time()
-        t_dispatch = time.perf_counter()
-        with timer.phase("dispatch"):
-            out = fn(
-                stack_for_mesh(datas), stack_for_mesh(cdatas),
-                p_bands, rho, jnp.asarray(B_pad, dtype),
-            )
-        p_bands = out.p  # warm start the next tile (reference keeps p)
+        tile_ann = timer.tile(t0)
+        tile_ann.__enter__()
+        out, admm_start_unix, t_dispatch = inflight
         if pi == 0:
             # where the mesh placed the bands (padding bands included)
             placed = out.p.sharding.devices_indices_map(out.p.shape)
@@ -693,11 +796,11 @@ def _run_distributed_inner(
                     f"{band.start}..{band.stop - 1}")
         if diffuse_idx is not None:
             zdiff_carry = out.Zspat_diff  # lazy device array, no sync
-        # overlap: prepare tile t+1 (I/O + coherency dispatch) while
+        # overlap: prepare tile t+1 (I/O + band program dispatch) while
         # the mesh solves tile t on device
+        nxt = None
         if pi + 1 < len(pairs):
-            with timer.phase("prepare"):
-                prepared = _prepare_tile(pairs[pi + 1][1], zdiff_carry)
+            nxt = _prepare(pairs[pi + 1][1], zdiff_carry)
         # close the ADMM device window AFTER the overlap work: this is
         # the first sync on tile t's outputs, so dispatch->here is the
         # tile's measured mesh-ADMM wall-time, attributed to synthetic
@@ -705,107 +808,127 @@ def _run_distributed_inner(
         with timer.phase("solve-wait"):
             out = jax.block_until_ready(out)
         admm_seconds = time.perf_counter() - t_dispatch
+        fratios = [float(f) for f in np.asarray(prepared["fratio"])]
+        # outside the phases: the synthetic spans hang off the tile span
         band_secs, straggler = _emit_admm_attribution(
             tracer, elog, log, t0, admm_seconds, admm_start_unix,
             fratios, Nf, nadmm, Nf_pad // ndev,
             max(cfg.max_emiter, 2), cfg.max_emiter,
             cluster_groups=max(cfg.consensus_cluster_groups, 1))
         note_activity("tile", name=f"tile{t0}", seconds=admm_seconds)
-        if mdl:
-            # AIC/MDL consensus-order scan on this tile's rho-scaled
-            # solutions (the master's -M path at admm==0,
-            # sagecal_master.cpp:986-993)
-            from sagecal_tpu.parallel.spatial import (
-                minimum_description_length,
-            )
+        with timer.phase("finish"):
+            if mdl:
+                # AIC/MDL consensus-order scan on this tile's rho-scaled
+                # solutions (the master's -M path at admm==0,
+                # sagecal_master.cpp:986-993)
+                from sagecal_tpu.parallel.spatial import (
+                    minimum_description_length,
+                )
 
-            w = np.asarray(fratios[:Nf])
-            Jst = (
-                np.asarray(out.p[:Nf], np.float64).reshape(Nf, M, -1)
-                * w[:, None, None] * np.asarray(rho_m)[None, :, None]
+                w = np.asarray(fratios[:Nf])
+                Jst = (
+                    np.asarray(out.p, np.float64)[:Nf].reshape(Nf, M, -1)
+                    * w[:, None, None] * np.asarray(rho_m)[None, :, None]
+                )
+                aic, mdl_s, k_aic, k_mdl = minimum_description_length(
+                    Jst, rho_m, freqs, freq0, weight=w,
+                    Kstart=1, Kfinish=max(cfg.npoly, 2),
+                )
+                log(f"tile {t0} MDL: best order AIC={k_aic} MDL={k_mdl} "
+                    f"(aic {np.array2string(aic, precision=2)}, "
+                    f"mdl {np.array2string(mdl_s, precision=2)})")
+        with timer.phase("residual"):
+            # per channel, from the per-band J or, with -U, the global
+            # B_f Z (sagecal_slave.cpp:861-979 use_global_solution path)
+            res_dev = residual_fn(
+                prepared["full"], prepared["coh_chan"],
+                prepared["cdata"].chunk_map, prepared["cdata"].nchunk,
+                out.p, out.Z, B_dev)
+        if nxt is not None:
+            inflight = _dispatch(nxt, out.p)
+        with timer.phase("residual"):
+            res = np.asarray(jax.device_get(res_dev))
+        with timer.phase("write"):
+            append_global_z(zfh, np.asarray(out.Z), N, cfg.npoly,
+                            nchunk_max)
+            with jax.default_device(cpu_device()):
+                jsol = np.asarray(params_to_jones(
+                    np.asarray(out.p)[:Nf])).reshape(
+                        Nf, M * nchunk_max, N, 2, 2)
+            for i in range(Nf):
+                solio.append_solutions(band_fhs[i], jsol[i])
+                # (F, 4, rows) -> (rows, F, 2, 2), channel by channel
+                handles[i].write_tile(
+                    t0, np.moveaxis(res[i], -1, 0).reshape(
+                        res.shape[-1], nchan, 2, 2),
+                    column=cfg.out_column)
+        with timer.phase("finish"):
+            # the carry of tile t: the next solve's warm start (the
+            # reference keeps p), and what the checkpoint holds
+            p_bands = out.p
+            traces.append(
+                (np.asarray(out.dual_res), np.asarray(out.primal_res))
             )
-            aic, mdl_s, k_aic, k_mdl = minimum_description_length(
-                Jst, rho_m, freqs, freq0, weight=w,
-                Kstart=1, Kfinish=max(cfg.npoly, 2),
-            )
-            log(f"tile {t0} MDL: best order AIC={k_aic} MDL={k_mdl} "
-                f"(aic {np.array2string(aic, precision=2)}, "
-                f"mdl {np.array2string(mdl_s, precision=2)})")
-        with timer.phase("solve-wait+write"):
-          append_global_z(zfh, out.Z, N, cfg.npoly, nchunk_max)
-          zfh.flush()
-          for i in range(Nf):
-            jsol = np.asarray(params_to_jones(out.p[i])).reshape(
-                M * nchunk_max, N, 2, 2
-            )
-            solio.append_solutions(band_fhs[i], jsol)
-            # -U: residuals from the GLOBAL consensus solution B_f Z
-            # instead of the per-band J (sagecal_slave.cpp:861-979
-            # use_global_solution path)
-            p_res = out.p[i]
-            if global_residual:
-                p_res = consensus.bz_for_freq(
-                    out.Z, jnp.asarray(B_pad[i], dtype)
-                ).reshape(M, nchunk_max, n8)
-            res = calculate_residuals(
-                datas[i], cdatas[i], p_res,
-            )
-            handles[i].write_tile(
-                t0, np.asarray(mat_of_flat(res)), column=cfg.out_column
-            )
-        traces.append(
-            (np.asarray(out.dual_res), np.asarray(out.primal_res))
-        )
-        _ckpt_update(pi)
-        if elog is not None:
-            # one event per tile = one consensus run of nadmm rounds;
-            # band-resolved residuals + the rho trajectory when the mesh
-            # fn was built with collect_trace
-            extra = {}
-            if out.primal_res_band is not None:
-                extra["primal_res_band"] = np.asarray(out.primal_res_band)
-                extra["dual_res_band"] = np.asarray(out.dual_res_band)
-                extra["rho_trace"] = np.asarray(out.rho_trace)
-            elog.emit(
-                "admm_round", tile=t0, nadmm=nadmm,
-                primal_res=np.asarray(out.primal_res),
-                dual_res=np.asarray(out.dual_res),
-                seconds=time.time() - tic,
-                admm_seconds=admm_seconds, band_seconds=band_secs,
-                straggler_ratio=straggler["ratio"],
-                phase_seconds=timer.tile_timings(), **extra,
-            )
-        if out.primal_res_band is not None:
-            # consensus watchdog: per-band residual trajectories ->
-            # ratio/trend/diverged (parallel.consensus.consensus_health
-            # via obs.quality.assess_consensus)
-            from sagecal_tpu.obs.quality import (
-                abort_if_diverged, assess_consensus,
-            )
-
-            verdict, reasons, health = assess_consensus(
-                np.asarray(out.primal_res_band),
-                np.asarray(out.dual_res_band),
-            )
+            dual, primal = traces[-1][0][-1], traces[-1][1][-1]
+            _ckpt_update(pi)
             if elog is not None:
-                elog.emit("consensus_health", tile=t0, verdict=verdict,
-                          reasons=reasons, ratio=health["ratio"],
-                          trend=health["trend"])
-                if verdict == "diverged":
-                    elog.emit("solver_diverged", reasons=reasons,
-                              tile=t0, app="distributed")
-            if verdict != "ok":
-                log(f"tile {t0}: consensus watchdog {verdict} "
-                    f"({', '.join(reasons)})")
-            if cfg.abort_on_divergence:
-                abort_if_diverged(elog, verdict, reasons, tile=t0,
-                                  app="distributed")
+                # one event per tile = one consensus run of nadmm rounds;
+                # band-resolved residuals + the rho trajectory when the
+                # mesh fn was built with collect_trace
+                extra = {}
+                if out.primal_res_band is not None:
+                    extra["primal_res_band"] = np.asarray(
+                        out.primal_res_band)
+                    extra["dual_res_band"] = np.asarray(out.dual_res_band)
+                    extra["rho_trace"] = np.asarray(out.rho_trace)
+                elog.emit(
+                    "admm_round", tile=t0, nadmm=nadmm,
+                    primal_res=np.asarray(out.primal_res),
+                    dual_res=np.asarray(out.dual_res),
+                    seconds=time.time() - tic,
+                    admm_seconds=admm_seconds, band_seconds=band_secs,
+                    straggler_ratio=straggler["ratio"],
+                    phase_seconds=timer.tile_timings(), **extra,
+                )
+            if out.primal_res_band is not None:
+                # consensus watchdog: per-band residual trajectories ->
+                # ratio/trend/diverged (parallel.consensus.
+                # consensus_health via obs.quality.assess_consensus)
+                from sagecal_tpu.obs.quality import (
+                    abort_if_diverged, assess_consensus,
+                )
+
+                verdict, reasons, health = assess_consensus(
+                    np.asarray(out.primal_res_band),
+                    np.asarray(out.dual_res_band),
+                )
+                if elog is not None:
+                    elog.emit("consensus_health", tile=t0,
+                              verdict=verdict, reasons=reasons,
+                              ratio=health["ratio"],
+                              trend=health["trend"])
+                    if verdict == "diverged":
+                        elog.emit("solver_diverged", reasons=reasons,
+                                  tile=t0, app="distributed")
+                if verdict != "ok":
+                    log(f"tile {t0}: consensus watchdog {verdict} "
+                        f"({', '.join(reasons)})")
+                if cfg.abort_on_divergence:
+                    abort_if_diverged(elog, verdict, reasons, tile=t0,
+                                      app="distributed")
+        timer.note("admm_rounds", nadmm)
+        timer.note("xsteps", int(out.xsteps))
+        jit_now = jit_clock()
+        timer.note("jit_s", jit_now[0] - jit_mark[0])
+        timer.note("jit_n", jit_now[1] - jit_mark[1])
+        jit_mark = jit_now
         log(
-            f"tile {t0}: dual {float(out.dual_res[-1]):.3e} primal "
-            f"{float(out.primal_res[-1]):.3e} ({time.time()-tic:.1f}s) "
-            f"[{timer.tile_summary()}]"
+            f"tile {t0}: dual {dual:.3e} primal {primal:.3e} "
+            f"({time.time()-tic:.1f}s) [{timer.tile_summary()}]"
         )
+        tile_ann.__exit__(None, None, None)
         tile_span.__exit__(None, None, None)
+        prepared = nxt
       log(f"phases: {timer.run_summary()}")
       if ckmgr is not None:
           ckmgr.flush()
@@ -836,6 +959,13 @@ def _run_distributed_inner(
           )
           log(f"spatial model plot -> {ppm_path}")
     finally:
+        # a run stopped at a tile line leaves the next tile's solve in
+        # flight: let it land before its inputs' files and threads go
+        if inflight is not None:
+            try:
+                jax.block_until_ready(inflight[0])
+            except Exception as e:  # the error that stopped the run wins
+                log(f"distributed: in-flight solve failed on exit: {e!r}")
         # reap every band's prefetch thread even on a mid-loop failure;
         # the audit exit is idempotent (already closed on the happy
         # path above)

@@ -21,6 +21,19 @@ scope          code
 ``finalize``   ``solvers/sage._finalize``: the final predict and bundling
 =============  ==========================================================
 
+The multi-band programs of ``parallel/mesh.py`` name theirs the same way:
+
+=============  ==========================================================
+scope          code
+=============  ==========================================================
+``admm``       the consensus ADMM of ``make_admm_mesh_fn``, with ``plain``
+               (the admm-0 solve of every local slot), ``xstep`` (the
+               augmented solve of a later round's active slot) and
+               ``zstep`` (the Z update and its collectives)
+``bands``      the band programs: ``coh`` (channel average and
+               coherencies) and ``residual`` (per-channel residuals)
+=============  ==========================================================
+
 :func:`scoped` names a function's ops; :func:`scope_path` reads the
 scope out of an ``op_name``;
 :func:`hlo_scopes` maps each instruction of an optimized HLO text to its
@@ -34,8 +47,10 @@ import re
 from typing import Callable, Dict, Iterable, Mapping
 
 TILE_SCOPES = ("em", "lbfgs", "finalize")
-SCOPE_NAMES = frozenset(TILE_SCOPES + ("residual", "solve", "rsd", "tr",
-                                       "tcg", "nu"))
+MESH_SCOPES = ("admm", "bands")
+SCOPE_NAMES = frozenset(TILE_SCOPES + MESH_SCOPES + (
+    "residual", "solve", "rsd", "tr", "tcg", "nu", "plain", "xstep",
+    "zstep", "coh"))
 UNSCOPED = "(unscoped)"
 
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")

@@ -42,11 +42,23 @@ Multi-host scaling: build the Mesh over ``jax.devices()`` spanning
 hosts (``jax.distributed.initialize``); the same psum/all_gather ride
 ICI inside a slice and DCN across — no code change, matching SURVEY.md
 section 5's mapping.
+
+The sub-band pipeline around the ADMM runs on the same mesh: the band
+programs of :func:`make_band_prep_fn` (channel average, coherencies)
+and :func:`make_band_residual_fn` (per-channel residuals) compute each
+band on the device that holds it.  Every op of these programs is named
+(``obs/scopes.py``): ``admm`` with ``plain`` (the admm-0 solves),
+``xstep`` (the augmented solve of the active slot) and ``zstep`` (the Z
+update and its collectives), and ``bands`` with ``coh`` and
+``residual``.  All three trace under HIGHEST matmul precision
+(``utils/precision.true_f32``): at the TPU's default single bf16 pass
+the predicted coherencies and residuals lose three digits (resid_gap
+1.2e-2 to 1.4e-2 on the v5e, against the plain reference).
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -55,11 +67,17 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from sagecal_tpu.core.types import VisData, jones_to_params, params_to_jones
 from sagecal_tpu.obs.perf import instrumented_jit
+from sagecal_tpu.obs.scopes import scoped
 from sagecal_tpu.parallel import consensus
 from sagecal_tpu.parallel.admm import admm_sagefit, factor_schedule
 from sagecal_tpu.parallel.manifold import manifold_average
 from sagecal_tpu.solvers.lm import LMConfig
-from sagecal_tpu.solvers.sage import SM_LM_LBFGS, ClusterData
+from sagecal_tpu.solvers.sage import (
+    SM_LM_LBFGS,
+    ClusterData,
+    predict_full_model,
+)
+from sagecal_tpu.utils.precision import true_f32
 
 
 class AdmmResult(NamedTuple):
@@ -76,6 +94,9 @@ class AdmmResult(NamedTuple):
     primal_res_band: Optional[jax.Array] = None  # (nadmm, Nf) per-band ||J-BZ||
     dual_res_band: Optional[jax.Array] = None  # (nadmm, Nf) rho||B dZ|| per band
     rho_trace: Optional[jax.Array] = None  # (nadmm, Nf, M) penalty trajectory
+    # int32: band x-step solves run, summed over the mesh (the admm-0
+    # solve of every local slot plus one active slot per later round)
+    xsteps: Optional[jax.Array] = None
 
 
 class SpatialConfig(NamedTuple):
@@ -282,6 +303,8 @@ def make_admm_mesh_fn(
             cluster_slice=cluster_slice,
         )
 
+    @scoped("admm")
+    @true_f32
     def local_loop(data: VisData, cdata: ClusterData, p0, rho, B_g):
         # all array leaves carry the local sub-band group axis G
         G, M, nchunk_max, n8 = p0.shape
@@ -321,13 +344,15 @@ def make_admm_mesh_fn(
             group_arr = jnp.asarray(group_np, jnp.int32)
 
         # ---- admm 0: plain solve of every local slot -------------------
-        def plain_one(_, inp):
+        def plain_one(n, inp):
             d_g, c_g, p_g, rho_g = inp
             r = _fit(d_g, c_g, p_g, zeros_g, zeros_g,
                      jnp.zeros_like(rho_g), plain_emiter)
-            return None, r.p
+            return n + 1, r.p
 
-        _, p = jax.lax.scan(plain_one, None, (data, cdata, p0, rho))
+        with jax.named_scope("plain"):
+            nx0, p = jax.lax.scan(plain_one, jnp.int32(0),
+                                  (data, cdata, p0, rho))
 
         if use_manifold_align:
             # master-side unitary-ambiguity fix over ALL Nf sub-bands
@@ -397,15 +422,17 @@ def make_admm_mesh_fn(
 
         # ---- round-0 consensus -----------------------------------------
         if zmode == "grouped":
-            Z = _zstep_grouped(_flat(Yhat), rho, B_g, axis_name)
+            with jax.named_scope("zstep"):
+                Z = _zstep_grouped(_flat(Yhat), rho, B_g, axis_name)
         else:
             # transpose reduction (arXiv:1504.02147): the basis-sized
             # Gram numerator lives psum_scatter'd over the solution
             # axis, so each device solves only its K/ndev shard of Z and
             # per-round collectives carry Gram deltas, never full
             # (M, Npoly, K) stacks.
-            B_full = jax.lax.all_gather(B_g, axis_name, axis=0,
-                                        tiled=True)
+            with jax.named_scope("zstep"):
+                B_full = jax.lax.all_gather(B_g, axis_name, axis=0,
+                                            tiled=True)
 
             def _num_scatter(Yhat_flat, weights=None):
                 terms = jax.vmap(consensus.accumulate_z_term)(
@@ -464,10 +491,11 @@ def make_admm_mesh_fn(
                 bz = jnp.moveaxis(got, 0, 1).reshape(Mg, K)
                 return _unflat(bz, nchunk_max, n8)
 
-            num_shard = _num_scatter(_flat(Yhat))
-            Bii0 = _den_inv(rho)
-            Zsh = consensus.update_global_z(num_shard, Bii0)
-            Z = jax.lax.all_gather(Zsh, axis_name, axis=2, tiled=True)
+            with jax.named_scope("zstep"):
+                num_shard = _num_scatter(_flat(Yhat))
+                Bii0 = _den_inv(rho)
+                Zsh = consensus.update_global_z(num_shard, Bii0)
+                Z = jax.lax.all_gather(Zsh, axis_name, axis=2, tiled=True)
 
         BZ_all = jax.vmap(lambda g: bz_of(Z, g))(jnp.arange(G))
         Y = Yhat - rho[:, :, None, None] * BZ_all
@@ -486,7 +514,7 @@ def make_admm_mesh_fn(
 
         # ---- admm > 0: rotate over local slots -------------------------
         def one_iter(carry, it):
-            p, Y, Zc, rho, Yhat_all, Yhat_prev, p_prev, spstate = carry
+            p, Y, Zc, rho, Yhat_all, Yhat_prev, p_prev, spstate, nx = carry
             if have_sched:
                 slot_row = jax.lax.dynamic_index_in_dim(
                     slot_arr, it - 1, keepdims=False
@@ -545,8 +573,9 @@ def make_admm_mesh_fn(
                     BZ_g = jax.lax.dynamic_update_slice(
                         pad, BZ_g, (c0, i0, i0)
                     )
-            loc = _fit(d_g, c_g, p_g, Y_g, BZ_g, rho_g, max_emiter,
-                       cluster_slice=csl)
+            with jax.named_scope("xstep"):
+                loc = _fit(d_g, c_g, p_g, Y_g, BZ_g, rho_g, max_emiter,
+                           cluster_slice=csl)
             p1_g = loc.p
             if fine:
                 Yhat_act = sl(Y_g) + sl(rho_g)[:, None, None] * sl(p1_g)
@@ -560,79 +589,80 @@ def make_admm_mesh_fn(
             if use_spatial:
                 Zbar_flat, Xsp = spstate[0], spstate[1]
                 z_extra = alpha_sp[:, None, None] * Zbar_flat - Xsp
-            if zmode == "grouped":
-                if use_spatial:
-                    Z1 = _zstep_grouped(
-                        _flat(Yhat_all1), rho, B_g, axis_name,
-                        federated_alpha=alpha_sp, z_extra=z_extra,
-                        weights=w,
-                    )
-                else:
-                    Z1 = _zstep_grouped(_flat(Yhat_all1), rho, B_g,
-                                        axis_name, weights=w)
-                Zc1 = Z1
-                BZ1_g = bz_of(Z1, g)
-                BZ1_act = sl(BZ1_g)
-                dres = consensus.admm_dual_residual(Z1, Z)
-            else:
-                if use_staleness:
-                    num_shard1 = _num_scatter(_flat(Yhat_all1), weights=w)
-                else:
-                    # incremental transpose reduction: only the active
-                    # (slot, group) factor's Yhat moved this round, so
-                    # only its basis-outer-product delta crosses the
-                    # mesh (the group schedule is device-uniform, so
-                    # the Mg delta rows align across devices)
-                    old_act = (
-                        jax.lax.dynamic_slice(
-                            Yhat_all, (g, c0, i0, i0),
-                            (1, Mg, nchunk_max, n8),
-                        )[0]
-                        if fine else Yhat_all[g]
-                    )
-                    delta = consensus.accumulate_z_term(
-                        B_g[g], _flat(Yhat_act - old_act)
-                    )
-                    dsh = jax.lax.psum_scatter(
-                        delta, axis_name, scatter_dimension=2, tiled=True
-                    )
-                    if fine:
-                        cur = jax.lax.dynamic_slice(
-                            num_shard, (c0, i0, i0), (Mg, Npoly, Ks)
-                        )
-                        num_shard1 = jax.lax.dynamic_update_slice(
-                            num_shard, cur + dsh, (c0, i0, i0)
+            with jax.named_scope("zstep"):
+                if zmode == "grouped":
+                    if use_spatial:
+                        Z1 = _zstep_grouped(
+                            _flat(Yhat_all1), rho, B_g, axis_name,
+                            federated_alpha=alpha_sp, z_extra=z_extra,
+                            weights=w,
                         )
                     else:
-                        num_shard1 = num_shard + dsh
-                if den_static:
-                    Bii = Bii0
-                else:
-                    Bii = _den_inv(
-                        rho, weights=w,
-                        federated_alpha=alpha_sp if use_spatial else None,
-                    )
-                num_solve = num_shard1
-                if use_spatial:
-                    did_z = jax.lax.axis_index(axis_name)
-                    num_solve = num_solve + jax.lax.dynamic_slice_in_dim(
-                        z_extra, did_z * Ks, Ks, axis=2
-                    )
-                Zsh1 = consensus.update_global_z(num_solve, Bii)
-                if zmode == "reduced_gather":
-                    Z1 = jax.lax.all_gather(Zsh1, axis_name, axis=2,
-                                            tiled=True)
+                        Z1 = _zstep_grouped(_flat(Yhat_all1), rho, B_g,
+                                            axis_name, weights=w)
+                    Zc1 = Z1
                     BZ1_g = bz_of(Z1, g)
                     BZ1_act = sl(BZ1_g)
                     dres = consensus.admm_dual_residual(Z1, Z)
-                    Zc1 = (Z1, Zsh1, num_shard1)
                 else:
-                    BZ1_act = a2a_bz(Zsh1, slot_row, group_row, g)
-                    dd = (Zsh1 - Zsh).ravel()
-                    dres = jnp.sqrt(
-                        jax.lax.psum(jnp.sum(dd * dd), axis_name)
-                    ) / jnp.sqrt(jnp.asarray(M * Npoly * K, dd.dtype))
-                    Zc1 = (Zsh1, num_shard1)
+                    if use_staleness:
+                        num_shard1 = _num_scatter(_flat(Yhat_all1), weights=w)
+                    else:
+                        # incremental transpose reduction: only the active
+                        # (slot, group) factor's Yhat moved this round, so
+                        # only its basis-outer-product delta crosses the
+                        # mesh (the group schedule is device-uniform, so
+                        # the Mg delta rows align across devices)
+                        old_act = (
+                            jax.lax.dynamic_slice(
+                                Yhat_all, (g, c0, i0, i0),
+                                (1, Mg, nchunk_max, n8),
+                            )[0]
+                            if fine else Yhat_all[g]
+                        )
+                        delta = consensus.accumulate_z_term(
+                            B_g[g], _flat(Yhat_act - old_act)
+                        )
+                        dsh = jax.lax.psum_scatter(
+                            delta, axis_name, scatter_dimension=2, tiled=True
+                        )
+                        if fine:
+                            cur = jax.lax.dynamic_slice(
+                                num_shard, (c0, i0, i0), (Mg, Npoly, Ks)
+                            )
+                            num_shard1 = jax.lax.dynamic_update_slice(
+                                num_shard, cur + dsh, (c0, i0, i0)
+                            )
+                        else:
+                            num_shard1 = num_shard + dsh
+                    if den_static:
+                        Bii = Bii0
+                    else:
+                        Bii = _den_inv(
+                            rho, weights=w,
+                            federated_alpha=alpha_sp if use_spatial else None,
+                        )
+                    num_solve = num_shard1
+                    if use_spatial:
+                        did_z = jax.lax.axis_index(axis_name)
+                        num_solve = num_solve + jax.lax.dynamic_slice_in_dim(
+                            z_extra, did_z * Ks, Ks, axis=2
+                        )
+                    Zsh1 = consensus.update_global_z(num_solve, Bii)
+                    if zmode == "reduced_gather":
+                        Z1 = jax.lax.all_gather(Zsh1, axis_name, axis=2,
+                                                tiled=True)
+                        BZ1_g = bz_of(Z1, g)
+                        BZ1_act = sl(BZ1_g)
+                        dres = consensus.admm_dual_residual(Z1, Z)
+                        Zc1 = (Z1, Zsh1, num_shard1)
+                    else:
+                        BZ1_act = a2a_bz(Zsh1, slot_row, group_row, g)
+                        dd = (Zsh1 - Zsh).ravel()
+                        dres = jnp.sqrt(
+                            jax.lax.psum(jnp.sum(dd * dd), axis_name)
+                        ) / jnp.sqrt(jnp.asarray(M * Npoly * K, dd.dtype))
+                        Zc1 = (Zsh1, num_shard1)
             if use_spatial:
                 # cadenced spatial re-fit (sagecal_master.cpp:887-930)
                 do_sp = (it % spatial.cadence) == 0
@@ -701,7 +731,7 @@ def make_admm_mesh_fn(
                 prn, ddn = band_residuals(p1, Z1, Z, rho1)
                 ys = ys + (prn, ddn, rho1)
             return (p1, Y1, Zc1, rho1, Yhat_all1, Yhat_prev1, p_prev1,
-                    spstate1), ys
+                    spstate1, nx + 1), ys
 
         spstate0 = (
             (Zbar_flat0, Xsp0, Zspat0, jnp.zeros((), p0.dtype),
@@ -716,21 +746,22 @@ def make_admm_mesh_fn(
             Zc0 = (Z, Zsh, num_shard)
         else:
             Zc0 = (Zsh, num_shard)
-        init = (p, Y, Zc0, rho, Yhat, Yhat, p, spstate0)
+        init = (p, Y, Zc0, rho, Yhat, Yhat, p, spstate0, nx0)
         if collect_trace:
             # iteration-0 rows: residuals of the plain solve vs the first
             # consensus (dual term is 0 by construction, dZ = 0)
             prn0, _ = band_residuals(p, Z, Z, rho)
             rho0 = rho
         carry, ys = jax.lax.scan(one_iter, init, jnp.arange(1, nadmm))
-        (p, Y, Zc, rho, _, _, _, spstate) = carry
+        (p, Y, Zc, rho, _, _, _, spstate, nx) = carry
         if zmode == "grouped":
             Z = Zc
         elif zmode == "reduced_gather":
             Z = Zc[0]
         else:
             # one-time reassembly of the replicated consensus result
-            Z = jax.lax.all_gather(Zc[0], axis_name, axis=2, tiled=True)
+            with jax.named_scope("zstep"):
+                Z = jax.lax.all_gather(Zc[0], axis_name, axis=2, tiled=True)
         (dres, pres, sres) = ys[:3]
         dres = jnp.concatenate([jnp.zeros((1,), dres.dtype), dres])
         pres = jnp.concatenate([jnp.zeros((1,), pres.dtype), pres])
@@ -740,7 +771,8 @@ def make_admm_mesh_fn(
             spstate[4] if use_spatial and use_diff
             else jnp.zeros((1, 1), jnp.complex64)
         )
-        out = (p, Y, Z, rho, dres, pres, Zspat_out, sres, Zdiff_out)
+        out = (p, Y, Z, rho, dres, pres, Zspat_out, sres, Zdiff_out,
+               jax.lax.psum(nx, axis_name))
         if collect_trace:
             prn_t, ddn_t, rho_t = ys[3:]
             prn_t = jnp.concatenate([prn0[None], prn_t])
@@ -752,7 +784,7 @@ def make_admm_mesh_fn(
     fspec = P(axis_name)
     rspec = P()
     out_specs = (fspec, fspec, rspec, fspec, rspec, rspec, rspec, rspec,
-                 rspec)
+                 rspec, rspec)
     if collect_trace:
         # band-axis telemetry shards on axis 1 (axis 0 is the iteration)
         bspec = P(None, axis_name)
@@ -774,14 +806,15 @@ def make_admm_mesh_fn(
             check_vma=True,
         )
         out = sm(data_stack, cdata_stack, p0, rho, B)
-        p, Y, Z, rho_f, dres, pres, Zspat, sres, Zdiff = out[:9]
+        p, Y, Z, rho_f, dres, pres, Zspat, sres, Zdiff, nx = out[:10]
         extra = {}
         if collect_trace:
-            extra = dict(primal_res_band=out[9], dual_res_band=out[10],
-                         rho_trace=out[11])
+            extra = dict(primal_res_band=out[10], dual_res_band=out[11],
+                         rho_trace=out[12])
         return AdmmResult(
             p=p, Y=Y, Z=Z, rho=rho_f, dual_res=dres, primal_res=pres,
-            Zspat=Zspat, spat_res=sres, Zspat_diff=Zdiff, **extra,
+            Zspat=Zspat, spat_res=sres, Zspat_diff=Zdiff, xsteps=nx,
+            **extra,
         )
 
     def traced_fn(data_stack, cdata_stack, p0, rho, B):
@@ -812,3 +845,158 @@ def stack_for_mesh(items):
     sharding over the ``freq`` mesh axis.  Static (non-pytree) fields
     must be identical across items."""
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *items)
+
+
+# ---------------------------------------------------------------------------
+# the sub-band pipeline on the mesh
+
+
+def average_channels(vis, mask):
+    """One band's channel average on the device that holds it: the
+    solver input ``VisDataset.load_tile(average_channels=True)`` makes on
+    the host (data.cpp:665-700).  A row keeps the mean of its unmasked
+    channels when more than half of them are unmasked; otherwise it is
+    masked and reads 0.
+
+    vis (F, 4, rows) complex, mask (F, rows) -> (1, 4, rows), (1, rows).
+    The mask equals load_tile's.  So does the data on every unmasked
+    row; on a row that only the uv cut masks, load_tile keeps the mean
+    of the unflagged channels where this keeps 0, and no solve reads a
+    masked row."""
+    F = vis.shape[0]
+    good = mask > 0
+    ngood = jnp.sum(good, axis=0)  # (rows,)
+    ok = ngood > F // 2
+    wsum = jnp.sum(jnp.where(good[:, None, :], vis, 0), axis=0)
+    x = jnp.where(ok[None, :],
+                  wsum / jnp.maximum(ngood, 1)[None, :].astype(wsum.dtype),
+                  0)
+    return x[None], ok.astype(mask.dtype)[None]
+
+
+def stack_sources(clusters):
+    """The sky's clusters padded to one source count with zero-flux
+    sources (``pad_source_batch``, as ``build_cluster_data``'s batched
+    path does) and stacked: every leaf (M, S)."""
+    from sagecal_tpu.ops.rime import pad_source_batch
+
+    smax = max(int(c.ll.shape[0]) for c in clusters)
+    padded = [pad_source_batch(c, smax) for c in clusters]
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
+
+
+def make_band_prep_fn(mesh: Mesh, nchunks: Sequence[int], fdelta_chan: float,
+                      with_coherencies: bool = True,
+                      has_extended: bool = False, axis_name: str = "freq"):
+    """Build the jitted per-tile band program: each device averages the
+    channels of its own bands and predicts their coherencies.
+
+    ``prep(full, band_freq0, sources, rho_m)``:
+      full: VisData with (Nf, ...) leaves sharded over ``axis_name``, the
+        tile at full channel resolution; its ``deltaf`` static is the
+        bandwidth the solve's coherencies smear over;
+      band_freq0: (Nf,) each band's centre frequency (the averaged
+        channel's frequency when a band has more than one channel);
+      sources: :func:`stack_sources` of the sky, replicated;
+      rho_m: (M,) per-cluster penalties, replicated.
+    Returns ``(data, cdata, coh_chan, rho, fratio)``, all sharded as
+    :func:`make_admm_mesh_fn`'s ``fn`` takes them: the channel-averaged
+    VisData; its ClusterData; the per-channel coherencies (Nf, M, F, 4,
+    rows) for the residuals, smeared over one channel (``fdelta_chan``);
+    ``fratio`` (Nf,) the unflagged share of each band's averaged rows
+    and ``rho = fratio * rho_m`` (sagecal_master.cpp:709-723).  With
+    ``with_coherencies=False`` (skies the caller predicts band by band)
+    cdata and coh_chan are None.
+
+    Clusters are predicted vmapped, padded to one source count, as
+    ``build_cluster_data``'s batched path; zero-flux padding sources add
+    nothing."""
+    from sagecal_tpu.ops.rime import ShapeletTable, _predict_coherencies
+
+    nchunks = tuple(int(n) for n in nchunks)
+    spec = P(axis_name)
+
+    def coherencies(d, freqs, sources, fdelta):
+        empty = ShapeletTable.empty(d.u.dtype)
+        return jax.vmap(lambda s: _predict_coherencies(
+            d.u, d.v, d.w, freqs, s, empty, float(fdelta), 32,
+            has_extended, False, 0.0, 0.0))(sources)
+
+    def one_band(d, f0, sources, rho_m):
+        if d.vis.shape[0] > 1:
+            vis, mask = average_channels(d.vis, d.mask)
+            avg = d.replace(vis=vis, mask=mask,
+                            freqs=f0[None].astype(d.freqs.dtype))
+        else:
+            avg = d
+        fratio = jnp.mean(avg.mask)
+        rho = (fratio * rho_m).astype(rho_m.dtype)
+        if not with_coherencies:
+            return avg, None, None, rho, fratio
+        coh = coherencies(d, avg.freqs, sources, d.deltaf)
+        coh_chan = (coh if d.vis.shape[0] == 1 else
+                    coherencies(d, d.freqs, sources, fdelta_chan))
+        cmap = jnp.stack([
+            jnp.minimum(d.time_idx // -(-d.tilesz // n), n - 1)
+            .astype(jnp.int32) for n in nchunks])
+        nchunk = jnp.asarray(nchunks, jnp.int32)
+        return avg, ClusterData(coh, cmap, nchunk), coh_chan, rho, fratio
+
+    @scoped("bands")
+    @scoped("coh")
+    @true_f32
+    def local(full, band_freq0, sources, rho_m):
+        return jax.vmap(one_band, in_axes=(0, 0, None, None))(
+            full, band_freq0, sources, rho_m)
+
+    # no collectives: each device computes its own bands alone (the
+    # coherency scan's zero carry is not typed as varying over the mesh)
+    sm = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, P(), P()),
+                       out_specs=spec, check_vma=False)
+
+    @instrumented_jit(name="bands.coh")
+    def prep(full, band_freq0, sources, rho_m):
+        return sm(full, band_freq0, sources, rho_m)
+
+    return prep
+
+
+def make_band_residual_fn(mesh: Mesh, global_solution: bool = False,
+                          axis_name: str = "freq"):
+    """Build the jitted per-tile residual program: each device computes
+    ``V - sum_k J_k C_k J_k^H`` per channel for its own bands
+    (``calculate_residuals_multifreq``, residual.c:940).
+
+    ``residual(full, coh_chan, chunk_map, nchunk, p, Z, B)``: the
+    full-channel VisData stack and the per-channel coherencies of
+    :func:`make_band_prep_fn`, the solve's chunk maps and counts, the
+    per-band solutions ``p`` (Nf, M, nchunk_max, 8N), all sharded; ``Z``
+    (M, Npoly, K) replicated and ``B`` (Nf, Npoly) sharded.  With
+    ``global_solution`` the residuals use the consensus ``B_f Z`` in
+    place of ``p`` (the -U path, sagecal_slave.cpp:861-979).  Returns
+    (Nf, F, 4, rows) complex, sharded."""
+    spec = P(axis_name)
+
+    def one_band(d, coh, cmap, nchunk, p, b, Z):
+        if global_solution:
+            p = consensus.bz_for_freq(Z, b).reshape(p.shape)
+        return d.vis - predict_full_model(p, ClusterData(coh, cmap, nchunk),
+                                          d)
+
+    @scoped("bands")
+    @scoped("residual")
+    @true_f32
+    def local(full, coh_chan, cmap, nchunk, p, Z, B):
+        return jax.vmap(one_band, in_axes=(0, 0, 0, 0, 0, 0, None))(
+            full, coh_chan, cmap, nchunk, p, B, Z)
+
+    sm = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(spec, spec, spec, spec, spec, P(), spec),
+        out_specs=spec, check_vma=False)
+
+    @instrumented_jit(name="bands.residual")
+    def residual(full, coh_chan, chunk_map, nchunk, p, Z, B):
+        return sm(full, coh_chan, chunk_map, nchunk, p, Z, B)
+
+    return residual

@@ -57,13 +57,25 @@ def _config(**kw):
 
 
 def _compiled_text(data, cdata, p0, config):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     from sagecal_tpu.solvers.sage import sagefit_packed
 
     jax.clear_caches()  # trace anew: scopes are read at trace time
+    # and compile anew: the persistent cache, which an app run earlier in
+    # this process turns on, keys a program without its op metadata
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
     vis, coh = np.asarray(data.vis), np.asarray(cdata.coh)
-    return jax.jit(sagefit_packed, static_argnames=("config",)).lower(
-        data.replace(vis=None), cdata._replace(coh=None), vis.real,
-        vis.imag, coh.real, coh.imag, p0, config=config).compile().as_text()
+    try:
+        return jax.jit(sagefit_packed, static_argnames=("config",)).lower(
+            data.replace(vis=None), cdata._replace(coh=None), vis.real,
+            vis.imag, coh.real, coh.imag, p0,
+            config=config).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        cc.reset_cache()
 
 
 @pytest.fixture(scope="module")
