@@ -5,7 +5,9 @@ Redesign of ``run_fullbatch_calibration``
 load -> precalculate coherencies -> SAGE solve -> write solutions ->
 residuals -> divergence guard.  The pthread/GPU pipeline orchestration
 of the reference dissolves into jitted solver calls; the host side only
-streams tiles and files.
+streams tiles and files.  The loop is software-pipelined by one solve:
+the next tile's solve is dispatched as soon as this tile's gains pass
+the guard, so this tile's residual and write run while the chip solves.
 """
 
 from __future__ import annotations
@@ -390,7 +392,22 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
           tile_ann.__exit__(None, None, None)
           tile_span.__exit__(None, None, None)
 
-      prepared = None
+      def _dispatch(prep):
+          """Whiten one prepared tile and dispatch its solve from the
+          current (p, rng_key): the whole SAGE/EM solve is one async jit
+          dispatch to the chip (solvers/sage.py sagefit_packed)."""
+          full_, data_, cdata_full_, cdata_ = prep
+          if cfg.whiten:
+              wts = jnp.sqrt(whiten_uv_weights(data_.u, data_.v,
+                                               meta.freq0))
+              data_ = data_.replace(vis=data_.vis * wts[None, None, :],
+                                    mask=data_.mask * (wts[None, :] > 0))
+          with timer.phase("solve"):
+              out_ = solve_tile(data_, cdata_, p, scfg, key=rng_key,
+                                device=accel)
+          return out_, full_, cdata_full_
+
+      prepared = inflight = None
       jit_mark = jit_clock()
       if pairs:
           with timer.phase("load+coh"):
@@ -401,11 +418,12 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
         tile_span.__enter__()
         tile_ann = timer.tile(t0)
         tile_ann.__enter__()
-        full, data, cdata_full, cdata = prepared
+        nxt = pairs[pi + 1][1] if pi + 1 < len(pairs) else None
 
         if cfg.simulation_mode:
             # predict / add / subtract (fullbatch_mode.cpp:536-591);
             # corrupt with the tile's own solution interval
+            full, _, cdata_full, _ = prepared
             psim = None
             if jones_intervals is not None:
                 ti = min(tile_no, jones_intervals.shape[0] - 1)
@@ -419,9 +437,9 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                 ignore_clusters=ignore_idx, ccid_index=ccid_index,
                 rho=cfg.correction_rho, phase_only=cfg.phase_only_correction,
             )
-            if pi + 1 < len(pairs):
+            if nxt is not None:
                 with timer.phase("load+coh"):
-                    prepared = _prepare(pairs[pi + 1][1])
+                    prepared = _prepare(nxt)
             ds.write_tile(t0, np.asarray(mat_of_flat(out_vis)), column="model")
             if elog is not None:
                 elog.emit("tile_simulated", tile=t0,
@@ -431,20 +449,17 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
             _end_tile()
             continue
 
-        if cfg.whiten:
-            wts = jnp.sqrt(whiten_uv_weights(data.u, data.v, meta.freq0))
-            data = data.replace(vis=data.vis * wts[None, None, :],
-                                mask=data.mask * (wts[None, :] > 0))
-        with timer.phase("solve"):
-            # the whole SAGE/EM solve is one jit dispatch to the chip
-            # (solvers/sage.py sagefit_packed)
-            out = solve_tile(data, cdata, p, scfg, key=rng_key,
-                             device=accel)  # async dispatch
+        # software pipeline by one solve: the chain's first solve is
+        # dispatched here, every later one by the tile before it, as
+        # soon as its warm start is known (below)
+        if inflight is None:
+            inflight = _dispatch(prepared)
+        out, full, cdata_full = inflight
         # overlap: next tile's load + coherency dispatch runs while the
         # device solves this tile
-        if pi + 1 < len(pairs):
+        if nxt is not None:
             with timer.phase("load+coh"):
-                prepared = _prepare(pairs[pi + 1][1])
+                prepared = _prepare(nxt)
         with timer.phase("solve-wait"):
             res0, res1 = float(out.res_0), float(out.res_1)
         with timer.phase("finish"):
@@ -498,6 +513,11 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
                 abort_if_diverged(elog, q_verdict, q_reasons,
                                   tile=t0, app="fullbatch")
 
+        # the next solve needs only (p, rng_key), final from here on:
+        # dispatch it now, so this tile's residual and write run on the
+        # host while the chip solves the next tile
+        inflight = _dispatch(prepared) if nxt is not None else None
+        with timer.phase("finish"):
             # append solution columns (fullbatch_mode.cpp:595-605)
             jsol = np.asarray(params_to_jones(p)).reshape(
                 M * nchunk_max, N, 2, 2)
@@ -579,6 +599,9 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
         for k in ("tr_steps", "tcg_steps", "tr_capped", "rsd_steps",
                   "rsd_moved", "lbfgs_iters"):
             timer.note(k, steps[k])
+        # 1: this tile's residual and write ran with the next solve
+        # already dispatched
+        timer.note("ahead", int(inflight is not None))
         jit_now = jit_clock()
         timer.note("jit_s", jit_now[0] - jit_mark[0])
         timer.note("jit_n", jit_now[1] - jit_mark[1])

@@ -371,7 +371,9 @@ class _StampedOut(io.StringIO):
 def test_tile_line_phases_cover_the_tile_period(tmp_path):
     """CLI run at rehearsal size: the top-level phases of each tile after
     the first cover at least 95% of the period between its tile line
-    and the one before, and the line carries the solver's counts."""
+    and the one before, and the line carries the solver's counts.  Every
+    tile but the last runs its residual and write with the next tile's
+    solve already dispatched (``ahead=1``)."""
     from test_apps import CLUSTER, SKY, _make_dataset
 
     from sagecal_tpu.apps.cli import main
@@ -404,3 +406,5 @@ def test_tile_line_phases_cover_the_tile_period(tmp_path):
                              "jit_s", "jit_n"):
             assert k in vals, (k, line)
         assert int(vals["tr_steps"]) > 0
+    ahead = [re.search(r" ahead=(\d+) ", line) for _, line in out.lines]
+    assert [int(m.group(1)) for m in ahead] == [1, 1, 1, 0], out.lines
