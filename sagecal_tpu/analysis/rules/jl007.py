@@ -13,8 +13,7 @@ a carry-named parameter that is neither static nor donated is
 reported.  Report-only by default, because donation is *only* safe
 when every caller treats the argument as consumed — entries whose
 callers reuse the args tuple (the lm/os-lm micro-benchmark harnesses,
-``bench.py`` timing loops) must stay undonated and live in the
-baseline instead.
+timing loops) must stay undonated and live in the baseline instead.
 """
 
 from __future__ import annotations

@@ -161,11 +161,9 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
         p = jnp.broadcast_to(eye, (M, nchunk_max, 8 * N)).astype(dtype)
     pinit = p
 
-    # telemetry (obs/): per-iteration solver traces ride along as extra
-    # jitted outputs when SAGECAL_TELEMETRY=1; the JSONL event log gets
-    # the manifest now and per-tile events in the loop below
+    # telemetry (obs/): with SAGECAL_TELEMETRY=1 the JSONL event log
+    # gets the manifest now and per-tile events in the loop below
     from sagecal_tpu.obs import RunManifest, default_event_log, telemetry_enabled
-    from sagecal_tpu.obs.records import sage_convergence_records
 
     scfg = SageConfig(
         max_emiter=cfg.max_emiter, max_iter=cfg.max_iter,
@@ -177,7 +175,6 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
         # quality watchdog below validates the solves it produces
         coh_dtype=(cfg.coh_dtype
                    if cfg.use_fused_predict and not cfg.use_f64 else "f32"),
-        collect_telemetry=telemetry_enabled(),
         # quality side outputs feed the watchdog: needed whenever
         # telemetry records them OR the run must be able to abort
         collect_quality=telemetry_enabled() or cfg.abort_on_divergence,
@@ -585,8 +582,6 @@ def _run_fullbatch_host(cfg: RunConfig, log, accel):
             warm_start = bool(pi > 0 or resume_done > 0
                               or cfg.init_solutions)
             if elog is not None:
-                for rec in sage_convergence_records(out.telemetry):
-                    elog.emit("cluster_convergence", tile=t0, **rec)
                 elog.emit(
                     "tile_done", tile=t0, res0=res0, res1=res1,
                     mean_nu=mean_nu, diverged=bool(diverged),

@@ -127,7 +127,6 @@ class StreamCalibrator:
         """(cold, warm) solver configs.  Warm budgets only shrink the
         cold ones — a degenerate config (warm > cold) silently clamps
         so the warm window never does MORE work than the cold one."""
-        from sagecal_tpu.obs import telemetry_enabled
         from sagecal_tpu.solvers.sage import SageConfig
 
         cfg = self.cfg
@@ -136,7 +135,6 @@ class StreamCalibrator:
             solver_mode=cfg.solver_mode,
             nulow=cfg.nulow, nuhigh=cfg.nuhigh,
             randomize=cfg.randomize,
-            collect_telemetry=telemetry_enabled(),
             collect_quality=True,
         )
         cold = SageConfig(max_emiter=cfg.max_emiter,

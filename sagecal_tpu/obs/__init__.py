@@ -1,13 +1,10 @@
-"""Structured telemetry: metrics, per-iteration traces, manifests, events.
+"""Structured telemetry: metrics, manifests, events, spans.
 
-Four pieces (SURVEY section 5 "observability"):
+The pieces (SURVEY section 5 "observability"):
 
 - :mod:`sagecal_tpu.obs.registry` — host-side counters/gauges/histograms
   with Prometheus text export; a shared no-op registry when telemetry is
   off so instrumented call sites never branch.
-- :mod:`sagecal_tpu.obs.records` — fixed-shape per-iteration solver
-  trace records (``IterTrace``) carried *through* jit/scan/while_loop as
-  auxiliary pytree outputs; host-callback-free by construction.
 - :mod:`sagecal_tpu.obs.events` — ``RunManifest`` + append-only JSONL
   event log (``SAGECAL_TELEMETRY=1`` / ``SAGECAL_EVENT_LOG=...``).
 - :mod:`sagecal_tpu.obs.perf` — performance observability:

@@ -209,17 +209,6 @@ def _cmd_events(args) -> int:
           f"{span:.1f}s span")
     for t in sorted(by_type):
         print(f"  {t}: {by_type[t]}")
-    # convergence digest: final cost per cluster record
-    conv = [e for e in evs if e.get("type") == "cluster_convergence"]
-    if conv:
-        finals = []
-        for e in conv:
-            costs = _finite(e.get("cost", []))
-            if costs:
-                finals.append(costs[-1])
-        if finals:
-            print(f"  convergence: {len(conv)} cluster records, "
-                  f"final cost min={min(finals):.4g} max={max(finals):.4g}")
     admm = [e for e in evs if e.get("type") == "admm_round"]
     if admm:
         last = admm[-1]
@@ -296,7 +285,7 @@ def _cmd_perf(args) -> int:
 
 def _load_record(path: str) -> Optional[dict]:
     """A bench record: a JSON dict, or the last ``bench_result``-shaped
-    line of a JSONL stream (bench.py prints one record per line)."""
+    line of a JSONL stream (one record per line)."""
     with open(path, "r", encoding="utf-8") as f:
         text = f.read()
     try:

@@ -3,13 +3,13 @@
 Every app run can emit a structured, append-only event stream: one JSON
 object per line, first line a :class:`RunManifest` snapshot (platform,
 device kind, precision config, kernel path), then per-tile / per-round
-events (phase timings, convergence records, ADMM residual traces, bench
-outcomes).  The log is plain JSONL so it greps/joins with standard
+events (phase timings, solver step counts, ADMM residual traces,
+bench outcomes).  The log is plain JSONL so it greps/joins with standard
 tools and round-trips losslessly through :func:`read_events`.
 
 Everything here is host-side and host-callback-free: jitted solver code
-returns telemetry as auxiliary pytree outputs (obs/records.py) and the
-app feeds them to an :class:`EventLog` after the solve returns.
+returns its counts and quality records as auxiliary pytree outputs and
+the app feeds them to an :class:`EventLog` after the solve returns.
 
 Enable with ``SAGECAL_TELEMETRY=1``; pick the path with
 ``SAGECAL_EVENT_LOG=/path/to/run.jsonl`` (default

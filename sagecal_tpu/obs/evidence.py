@@ -125,7 +125,7 @@ def classify_history_row(row: dict) -> Optional[str]:
 
 
 def bench_evidence_classes(platform: Optional[str]) -> Dict[str, str]:
-    """The per-metric override map for a full bench.py record: the
+    """The per-metric override map for a full bench record: the
     headline timing metrics inherit the record-level (platform) class,
     while satellite metrics carry the class of how THEY were actually
     measured — AOT cost-analysis, HLO inspection, or the CPU/f64

@@ -108,8 +108,8 @@ def note_compile(name: str, lower_seconds: float, compile_seconds: float,
                  signature: str = "", aot: bool = True) -> dict:
     """Record one compilation of ``name`` into the registry, the
     per-function aggregates, and the compile event stream.  Public so
-    code that already AOT-compiles itself (bench.py) reports through
-    the same channel as :func:`instrumented_jit`."""
+    code that already AOT-compiles itself (the serve executable cache)
+    reports through the same channel as :func:`instrumented_jit`."""
     with _LOCK:
         st = _FN_STATS.setdefault(name, {
             "compiles": 0, "lower_seconds": 0.0, "compile_seconds": 0.0,
@@ -241,7 +241,7 @@ def enable_persistent_compilation_cache() -> str:
     :func:`compile_cache_dir` and install the cache-hit monitoring
     listener, so a second process compiling the same program
     deserializes the cached executable instead of re-running XLA
-    codegen.  Every app entry and bench.py call this once at startup.
+    codegen.  Every app entry calls this once at startup.
     The min-compile-time floor is dropped to 0 s: calibration programs
     are few and large, so caching everything is strictly a win."""
     import jax

@@ -1,10 +1,10 @@
 """Metrics registry: counters / gauges / histograms, Prometheus export.
 
 Host-side half of the telemetry layer (see :mod:`sagecal_tpu.obs`).
-Nothing in here touches a tracer: jitted code returns fixed-shape trace
-records (:mod:`sagecal_tpu.obs.records`) as auxiliary pytree outputs,
-and the *host* feeds the materialized numbers into this registry after
-the solve returns.  That keeps collection host-callback-free — no
+Nothing in here touches a tracer: jitted code returns fixed-shape
+counts and quality records as auxiliary pytree outputs, and the *host*
+feeds the materialized numbers into this registry after the solve
+returns.  That keeps collection host-callback-free — no
 ``io_callback``/``debug.callback`` inside traced code, so the fused
 Pallas path and AOT compilation are unaffected.
 

@@ -1,10 +1,8 @@
 """Per-``device_kind`` roofline model: peaks, intensity, per-kernel MFU.
 
-The observability stack's hardware-truth layer (PR 16).  Before this
-module the repo carried exactly one peak constant
-(``V5E_BF16_PEAK_FLOPS`` in bench.py) and two v5e-specific run-level
-gauges — correct on the one TPU the paper was benched on, silently
-wrong everywhere else, and blind below the whole-run boundary.  Here:
+The observability stack's hardware-truth layer: per-device peaks in
+place of a single hard-coded v5e constant, and attribution below the
+whole-run boundary.  Here:
 
 - :data:`PEAK_TABLE` — editable per-``device_kind`` peaks (FLOP/s per
   dtype + HBM GB/s).  v5e is the hardware-validated entry; the ``cpu``

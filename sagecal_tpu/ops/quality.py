@@ -5,11 +5,11 @@ per-cluster chi^2 inside ``sagefit_visibilities``, the Student's-t nu
 after each EM pass) and post-hoc influence maps (``-i``,
 ops/diagnostics.py).  This module turns those signals into FIXED-SHAPE
 arrays computed *inside* the jitted solves so they ride out of
-jit/scan/while_loop as auxiliary pytree outputs — the same contract as
-:mod:`sagecal_tpu.obs.records`: no host callbacks, no data-dependent
-shapes, statically gated (``collect_quality=False`` keeps every slot
-``None``, an empty pytree, so the jitted output signature is unchanged
-and enabling quality can never cost a recompile of the disabled path).
+jit/scan/while_loop as auxiliary pytree outputs: no host callbacks,
+no data-dependent shapes, statically gated (``collect_quality=False``
+keeps every slot ``None``, an empty pytree, so the jitted output
+signature is unchanged and enabling quality can never cost a recompile
+of the disabled path).
 
 Three reduction families:
 

@@ -47,10 +47,6 @@ class AdmmLocalResult(NamedTuple):
     p: jax.Array  # (M, nchunk_max, 8N)
     res_0: jax.Array
     res_1: jax.Array
-    # tuple of per-EM-pass IterTrace pytrees (leading cluster axis) when
-    # collect_trace=True, else None — an empty pytree, so the jitted
-    # output signature is unchanged
-    trace: Optional[tuple] = None
 
 
 @true_f32
@@ -67,7 +63,6 @@ def admm_sagefit(
     solver_mode: int = SM_LM_LBFGS,
     nulow: float = 2.0,
     nuhigh: float = 30.0,
-    collect_trace: bool = False,
     cluster_slice=None,
 ) -> AdmmLocalResult:
     """One worker's ADMM x-update for one tile.
@@ -125,7 +120,6 @@ def admm_sagefit(
                     p_k, itmax=itmax + 15, nu0=nu0, nulow=nulow,
                     nuhigh=nuhigh,
                     admm_y=y_k, admm_bz=bz_k, admm_rho=rho_k,
-                    collect_trace=collect_trace,
                 )
             elif robust:
                 res, _ = rtr_solve_robust(
@@ -134,7 +128,6 @@ def admm_sagefit(
                     RTRConfig(itmax_rsd=itmax + 5, itmax_rtr=itmax + 10),
                     nu0=nu0, nulow=nulow, nuhigh=nuhigh,
                     admm_y=y_k, admm_bz=bz_k, admm_rho=rho_k,
-                    collect_trace=collect_trace,
                 )
             else:
                 res = rtr_solve(
@@ -142,9 +135,8 @@ def admm_sagefit(
                     p_k,
                     RTRConfig(itmax_rsd=itmax + 5, itmax_rtr=itmax + 10),
                     admm_y=y_k, admm_bz=bz_k, admm_rho=rho_k,
-                    collect_trace=collect_trace,
                 )
-            return res.p, res.trace
+            return res.p, None
         if robust_nu is not None:
             ed = _residual_flat(
                 p_k, coh_k, xeff, data.mask, data.ant_p, data.ant_q, cmap_k, None
@@ -158,24 +150,17 @@ def admm_sagefit(
             xeff, coh_k, data.mask, data.ant_p, data.ant_q, cmap_k, p_k,
             lm_config, sqrt_weights=sqrt_w,
             admm_y=y_k, admm_bz=bz_k, admm_rho=rho_k,
-            collect_trace=collect_trace,
         )
-        return res.p, res.trace
+        return res.p, None
 
     p = p0
-    traces = []
     for _ in range(max_emiter):
-        p, tr = em_residual_scan(data, cdata, p, (Y, BZ, rho), solve_one,
-                                 cluster_slice=cluster_slice)
-        if collect_trace:
-            traces.append(tr)
+        p, _ = em_residual_scan(data, cdata, p, (Y, BZ, rho), solve_one,
+                                cluster_slice=cluster_slice)
 
     full1 = predict_full_model(p, cdata, data)
     res_1 = _res_norm(data.vis - full1, data.mask, nreal)
-    return AdmmLocalResult(
-        p=p, res_0=res_0, res_1=res_1,
-        trace=tuple(traces) if collect_trace else None,
-    )
+    return AdmmLocalResult(p=p, res_0=res_0, res_1=res_1)
 
 
 def admm_dual_update(Y, p, BZ, rho):

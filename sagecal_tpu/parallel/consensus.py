@@ -277,8 +277,8 @@ def consensus_health(
 
     ``primal_res_band``/``dual_res_band``: (nadmm, Nf) per-round,
     per-band residuals (:class:`sagecal_tpu.parallel.mesh.AdmmResult`
-    with ``collect_trace``).  Returns ``(ratio (Nf,), trend (Nf,),
-    diverged (Nf,) bool)``:
+    of a mesh program built with per-band telemetry).  Returns
+    ``(ratio (Nf,), trend (Nf,), diverged (Nf,) bool)``:
 
     - ``ratio``: final primal/dual residual ratio — the standard ADMM
       balance diagnostic (Boyd §3.4.1; the reference's master prints the

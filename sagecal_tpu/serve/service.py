@@ -49,7 +49,6 @@ def _merge_sage_config(cfg, req: SolveRequest):
     """Request solver knobs (None = inherit) over the service-wide
     ServeConfig defaults -> (SageConfig, numerics fingerprint)."""
     from sagecal_tpu.elastic.checkpoint import config_fingerprint
-    from sagecal_tpu.obs import telemetry_enabled
     from sagecal_tpu.solvers.sage import SageConfig
 
     knobs = dict(
@@ -72,7 +71,6 @@ def _merge_sage_config(cfg, req: SolveRequest):
         and not cfg.use_f64
     coh_dtype = getattr(cfg, "coh_dtype", "f32")
     scfg = SageConfig(
-        collect_telemetry=False,  # batched lanes report via quality
         collect_quality=True,     # per-request verdicts are the product
         use_fused_predict=use_fused,
         coh_dtype=coh_dtype,
@@ -80,8 +78,7 @@ def _merge_sage_config(cfg, req: SolveRequest):
     )
     fp = config_fingerprint(use_f64=cfg.use_f64,
                             use_fused_predict=use_fused,
-                            coh_dtype=coh_dtype,
-                            collect=telemetry_enabled(), **knobs)
+                            coh_dtype=coh_dtype, **knobs)
     return scfg, fp
 
 

@@ -173,8 +173,6 @@ def choose_batched_path(data, cdata, p0, config: SageConfig):
         return "xla", f"N={n8 // 8} exceeds the kernel's NPAD={NPAD}"
     if config.param_bound > 0.0:
         return "xla", "param_bound uses the (XLA-only) bounded LBFGS"
-    if config.collect_telemetry:
-        return "xla", "telemetry traces are XLA-path only"
     if nchunk_max > 1:
         return "fused", "hybrid time chunks: batched kernel is nc==1 only"
     ant_p = np.asarray(data.ant_p)

@@ -120,8 +120,7 @@ def _armijo_bad(f_new, fold, alpha, product):
 def _armijo_rest(cost_fn, x, p, a0, fold, f_a0, product):
     """Armijo halving loop (lbfgs.c:444-475: at most 15 halvings) with
     the first trial's cost ``f_a0`` already in hand.  Returns
-    ``(alpha, halvings)`` — the halving count feeds the telemetry
-    line-search evaluation counter."""
+    ``(alpha, halvings)``."""
 
     def cond(st):
         ci, alpha, fnew = st
@@ -142,9 +141,6 @@ class LBFGSResult(NamedTuple):
     cost: jax.Array
     gradnorm: jax.Array
     iterations: jax.Array
-    # per-iteration IterTrace (obs.records) when collect_trace=True, else
-    # None — an empty pytree, so the jitted output signature is unchanged
-    trace: Optional[tuple] = None
 
 
 @true_f32
@@ -156,7 +152,6 @@ def lbfgs_fit(
     M: int = 7,
     memory: Optional[LBFGSMemory] = None,
     minibatch: bool = False,
-    collect_trace: bool = False,
     vg_fn: Optional[Callable] = None,
 ) -> LBFGSResult:
     """Generic LBFGS fit (``lbfgs_fit``, Dirac.h:175 / lbfgs.c:479,717).
@@ -224,16 +219,12 @@ def lbfgs_fit(
         batch_changed = jnp.asarray(False)
         alphabar = jnp.asarray(1.0, p0.dtype)
 
-    from sagecal_tpu.obs.records import init_trace, write_trace
-
-    trace0 = init_trace(itmax, (), p0.dtype) if collect_trace else None
-
     def cond(state):
-        ck, x, f, g, gradnrm, mem, done, trace = state
+        ck, x, f, g, gradnrm, mem, done = state
         return (ck < itmax) & (~done)
 
     def body(state):
-        ck, x, f, g, gradnrm, mem, done, trace = state
+        ck, x, f, g, gradnrm, mem, done = state
         pk = _two_loop_direction(g, mem)
         # Evaluate value_and_grad AT the first Armijo trial point: when
         # the full step passes the sufficient-decrease test (the common
@@ -250,6 +241,9 @@ def lbfgs_fit(
         product = ARMIJO_C * jnp.dot(pk, g)
         first_ok = ~_armijo_bad(f_t, f, a0, product)
 
+        # the cond also returns the iteration's cost evaluations: no
+        # caller reads them, but they keep the lowered program (and so
+        # its compile-cache key) the one every earlier release built
         def accept_first(_):
             return a0, f_t, g_t, jnp.ones((), x.dtype)
 
@@ -259,8 +253,8 @@ def lbfgs_fit(
             # first trial + each halving + the fused re-eval at alpha
             return alpha, fb, gb, 2.0 + halvings.astype(x.dtype)
 
-        alphak, f1, g1, ls_evals = jax.lax.cond(first_ok, accept_first,
-                                                backtrack, None)
+        alphak, f1, g1, _ = jax.lax.cond(first_ok, accept_first,
+                                         backtrack, None)
         step_ok = jnp.isfinite(alphak) & (jnp.abs(alphak) >= CLM_EPSILON)
         x1 = x + alphak * pk
         gradnrm1 = jnp.linalg.norm(g1)
@@ -308,27 +302,19 @@ def lbfgs_fit(
         g_next = jnp.where(step_ok, g1, g)
         gradnrm_next = jnp.where(step_ok, gradnrm1, gradnrm)
         done_next = (~step_ok) | (~grad_ok)
-        if trace is not None:
-            trace = write_trace(
-                trace, ck,
-                cost=f_next,
-                grad_norm=gradnrm_next,
-                step=alphak,
-                ls_evals=ls_evals,
-            )
         return (ck + 1, x_next, f_next, g_next, gradnrm_next, mem1,
-                done_next, trace)
+                done_next)
 
     from sagecal_tpu.utils.platform import match_vma
 
     start_done = ~(jnp.isfinite(gradnrm0) & (gradnrm0 > CLM_STOP_THRESH))
-    ck, x, f, g, gradnrm, mem, _, trace = jax.lax.while_loop(
+    ck, x, f, g, gradnrm, mem, _ = jax.lax.while_loop(
         cond, body,
         match_vma((jnp.asarray(0), p0, f0, g0, gradnrm0, memory,
-                   start_done, trace0), p0),
+                   start_done), p0),
     )
     return LBFGSResult(p=x, memory=mem, cost=f, gradnorm=gradnrm,
-                       iterations=ck, trace=trace)
+                       iterations=ck)
 
 
 def _bdot(a, b):
@@ -418,9 +404,9 @@ def _armijo_rest_batched(cost_fn, x, p, a0, fold, f_a0, product, live):
         return (jnp.where(b, ci + 1, ci), alpha1,
                 jnp.where(b, f1, fnew))
 
-    ci, alpha, _ = jax.lax.while_loop(
+    _, alpha, _ = jax.lax.while_loop(
         cond, body, (jnp.zeros(a0.shape, jnp.int32), a0, f_a0))
-    return alpha, ci
+    return alpha
 
 
 @true_f32
@@ -448,8 +434,7 @@ def lbfgs_fit_batched(
     Per-lane semantics match ``jax.vmap(lbfgs_fit)`` (same predicates,
     same masked-carry advancement — a lane whose own termination fires
     freezes while the others run), but not bit-identically: batched
-    reductions re-associate, like the rest of the serve batch path.
-    Telemetry traces are not collected on the batched path."""
+    reductions re-associate, like the rest of the serve batch path."""
     B, n = p0.shape
     if vg_fn is None:
         def vg_fn(x):
@@ -507,21 +492,19 @@ def lbfgs_fit_batched(
         need_bt = active & ~first_ok
 
         def accept_all(_):
-            return a0, f_t, g_t, jnp.ones((B,), x.dtype)
+            return a0, f_t, g_t
 
         def backtrack_some(_):
-            alpha, halvings = _armijo_rest_batched(
+            alpha = _armijo_rest_batched(
                 cost_fn, x, pk, a0, f, f_t, product, need_bt)
             fb, gb = vg_fn(x + alpha[:, None] * pk)
             f1 = jnp.where(need_bt, fb, f_t)
             g1 = jnp.where(need_bt[:, None], gb, g_t)
-            evals = jnp.where(need_bt, 2.0 + halvings.astype(x.dtype),
-                              1.0)
-            return alpha, f1, g1, evals
+            return alpha, f1, g1
 
         # one REAL branch (traced-scalar cond): the all-accept common
         # case costs exactly one fused (f, g) pass, like the solo path
-        alphak, f1, g1, _ = jax.lax.cond(
+        alphak, f1, g1 = jax.lax.cond(
             jnp.any(need_bt), backtrack_some, accept_all, None)
         step_ok = jnp.isfinite(alphak) & (jnp.abs(alphak) >= CLM_EPSILON)
         x1 = x + alphak[:, None] * pk
@@ -574,7 +557,7 @@ def lbfgs_fit_batched(
                    memory, start_done), p0),
     )
     return LBFGSResult(p=x, memory=mem, cost=f, gradnorm=gradnrm,
-                       iterations=ck, trace=None)
+                       iterations=ck)
 
 
 # jitted module entry with compile/recompile telemetry (obs/perf.py):
@@ -592,4 +575,4 @@ lbfgs_fit_jit = instrumented_jit(
     lbfgs_fit, name="lbfgs_fit",
     donate_argnames=("p0", "memory"),
     static_argnames=("cost_fn", "grad_fn", "itmax", "M", "minibatch",
-                     "collect_trace", "vg_fn"))
+                     "vg_fn"))

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from flax import struct
 
 from sagecal_tpu.core.types import corrupt_flat, params_to_jones, reals_of_flat
-from sagecal_tpu.obs.records import init_trace, write_trace
 from sagecal_tpu.ops.quality import SolveQuality, residual_quality
 from sagecal_tpu.utils.precision import true_f32
 
@@ -55,10 +54,8 @@ class LMResult(NamedTuple):
     cost0: jax.Array  # (nchunk,) initial cost
     cost: jax.Array  # (nchunk,) final cost
     iterations: jax.Array
-    # per-iteration IterTrace (obs.records) when collect_trace=True, else
-    # None — an empty pytree, so the jitted output signature is unchanged
-    trace: Optional[tuple] = None
-    # SolveQuality (ops.quality) when collect_quality=True, same contract
+    # SolveQuality (ops.quality) when collect_quality=True, else None —
+    # an empty pytree, so the jitted output signature is unchanged
     quality: Optional[SolveQuality] = None
 
 
@@ -224,7 +221,6 @@ def lm_solve(
     admm_y: Optional[jax.Array] = None,
     admm_bz: Optional[jax.Array] = None,
     admm_rho: Optional[jax.Array] = None,
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ) -> LMResult:
     """Solve min_p sum_rows ||vis - J_p C J_q^H||^2 per hybrid chunk.
@@ -294,16 +290,12 @@ def lm_solve(
         else jnp.minimum(config.itmax, itmax_dynamic)
     )
 
-    # trace is None (empty pytree) when collection is off, so the
-    # while_loop carry — and the jitted output signature — is unchanged
-    trace0 = init_trace(config.itmax, (nchunk,), p0.dtype) if collect_trace else None
-
     def cond(st):
-        it, p, cost, mu, nu, done, trace = st
+        it, p, cost, mu, nu, done = st
         return (it < it_bound) & (~jnp.all(done))
 
     def body(st):
-        it, p, cost, mu, nu, done, trace = st
+        it, p, cost, mu, nu, done = st
         JTJ, JTe, _ = _assemble_normal_eq(p, *args)
         JTe = JTe - aug_grad(p)
         n8 = p.shape[-1]
@@ -333,23 +325,15 @@ def lm_solve(
             jnp.linalg.norm(p1, axis=-1) + config.eps2
         )
         done1 = done | (g_inf <= config.eps1) | small_step | (cost1 <= config.eps3)
-        if trace is not None:
-            trace = write_trace(
-                trace, it,
-                cost=cost1,
-                grad_norm=g_inf,
-                step=jnp.linalg.norm(dp, axis=-1),
-                ls_evals=jnp.where(done, 0.0, 1.0).astype(cost1.dtype),
-            )
-        return it + 1, p1, cost1, mu1, nu1, done1, trace
+        return it + 1, p1, cost1, mu1, nu1, done1
 
     from sagecal_tpu.utils.platform import match_vma
 
     nu0 = jnp.full((nchunk,), 2.0, p0.dtype)
     done0 = jnp.zeros((nchunk,), bool)
-    it, p, cost, _, _, _, trace = jax.lax.while_loop(
+    it, p, cost, _, _, _ = jax.lax.while_loop(
         cond, body,
-        match_vma((jnp.asarray(0), p0, cost0, mu0, nu0, done0, trace0), p0),
+        match_vma((jnp.asarray(0), p0, cost0, mu0, nu0, done0), p0),
     )
     quality = None
     if collect_quality:
@@ -359,7 +343,7 @@ def lm_solve(
         quality = residual_quality(
             e1, p, ant_p, ant_q, chunk_map, nchunk
         )
-    return LMResult(p=p, cost0=cost0, cost=cost, iterations=it, trace=trace,
+    return LMResult(p=p, cost0=cost0, cost=cost, iterations=it,
                     quality=quality)
 
 
@@ -370,7 +354,6 @@ def os_lm_solve(
     sqrt_weights: Optional[jax.Array] = None,
     nsubsets: int = 4,
     key: Optional[jax.Array] = None,
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ) -> LMResult:
     """Ordered-subsets accelerated LM (``oslevmar_der_single_nocuda``,
@@ -393,25 +376,14 @@ def os_lm_solve(
     )
     p = p0
     cost0 = None
-    traces = []
     for s in range(nsubsets):
         m_s = mask * (subset_of_row == s)[None, :].astype(mask.dtype)
         res = lm_solve(
             vis, coh, m_s, ant_p, ant_q, chunk_map, p, sub_cfg, sqrt_weights,
-            collect_trace=collect_trace,
         )
         p = res.p
         if cost0 is None:
             cost0 = res.cost0 * nsubsets
-        if collect_trace:
-            traces.append(res.trace)
-    # per-subset traces concatenate on the iteration axis: the OS pass IS
-    # one LM run whose iterations cycle through subsets
-    trace = (
-        jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs, axis=0), *traces)
-        if collect_trace
-        else None
-    )
     final_cost = _cost_only(
         p, coh, vis, mask, ant_p, ant_q, chunk_map, p0.shape[0], sqrt_weights
     )
@@ -427,7 +399,7 @@ def os_lm_solve(
             e1, p, ant_p, ant_q, chunk_map, p0.shape[0]
         )
     return LMResult(p=p, cost0=cost0, cost=final_cost,
-                    iterations=jnp.asarray(config.itmax), trace=trace,
+                    iterations=jnp.asarray(config.itmax),
                     quality=quality)
 
 
@@ -441,7 +413,7 @@ from sagecal_tpu.obs.perf import instrumented_jit  # noqa: E402
 
 lm_solve_jit = instrumented_jit(
     lm_solve, name="lm_solve",
-    static_argnames=("collect_trace", "collect_quality"))
+    static_argnames=("collect_quality",))
 os_lm_solve_jit = instrumented_jit(
     os_lm_solve, name="os_lm_solve",
-    static_argnames=("nsubsets", "collect_trace", "collect_quality"))
+    static_argnames=("nsubsets", "collect_quality"))

@@ -91,16 +91,12 @@ def robust_lm_solve(
     nuhigh: float = 30.0,
     em_iters: int = 3,
     config: LMConfig = LMConfig(),
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ):
     """Robust LM: EM over (weights, nu) wrapping weighted LM solves
     (``rlevmar_der_single_nocuda``, robustlm.c; Dirac.h:744).
 
-    Returns (LMResult, nu).  With ``collect_trace`` the result's trace
-    stacks the EM stages in front: ``(em_iters + 1, itmax, nchunk)`` per
-    field (final weighted solve last), with the trace's ``nu`` field set
-    to the Student's-t nu in effect during each stage.
+    Returns (LMResult, nu).
 
     ``collect_quality`` additionally fills the result's quality slot
     (ops/quality.py): the final weighted solve's chi^2 attribution and
@@ -113,19 +109,11 @@ def robust_lm_solve(
         p, nu, sqrt_w = carry
         res = lm_solve(
             vis, coh, mask, ant_p, ant_q, chunk_map, p, config,
-            sqrt_weights=sqrt_w, collect_trace=collect_trace,
+            sqrt_weights=sqrt_w,
         )
         ed = _residual_flat(res.p, coh, vis, mask, ant_p, ant_q, chunk_map, None)
         sqrt_w_new, nu_new = update_w_and_nu(ed, nu, nulow, nuhigh, mask=mask8)
-        ys = res.cost
-        if collect_trace:
-            # nu in effect for this stage is the carried nu (it built the
-            # weights the solve just used)
-            tr = res.trace._replace(
-                nu=jnp.broadcast_to(nu, res.trace.nu.shape).astype(res.trace.nu.dtype)
-            )
-            ys = (res.cost, tr)
-        return (res.p, nu_new, sqrt_w_new), ys
+        return (res.p, nu_new, sqrt_w_new), None
 
     # E-step FIRST: weights from the residual at p0, so gross outliers are
     # suppressed before they can poison the first fit.  (The reference's
@@ -137,12 +125,11 @@ def robust_lm_solve(
         ed0, jnp.asarray(nu0, p0.dtype), nulow, nuhigh, mask=mask8
     )
     init = (p0, nu_e, sqrt_w0)
-    (p, nu, sqrt_w), ys = jax.lax.scan(em_step, init, None, length=em_iters)
+    (p, nu, sqrt_w), _ = jax.lax.scan(em_step, init, None, length=em_iters)
     # final weighted solve with converged weights
     res = lm_solve(
         vis, coh, mask, ant_p, ant_q, chunk_map, p, config,
-        sqrt_weights=sqrt_w, collect_trace=collect_trace,
-        collect_quality=collect_quality,
+        sqrt_weights=sqrt_w, collect_quality=collect_quality,
     )
     if collect_quality:
         from sagecal_tpu.ops.quality import weight_stats
@@ -152,16 +139,6 @@ def robust_lm_solve(
             nu=jnp.asarray(nu, p0.dtype), weight_hist=hist,
             downweighted_frac=down, flagged_frac=flag,
         ))
-    if collect_trace:
-        _, em_traces = ys  # IterTrace stacked (em_iters, itmax, ...)
-        final_tr = res.trace._replace(
-            nu=jnp.broadcast_to(nu, res.trace.nu.shape).astype(res.trace.nu.dtype)
-        )
-        full = jax.tree_util.tree_map(
-            lambda em, fin: jnp.concatenate([em, fin[None]], axis=0),
-            em_traces, final_tr,
-        )
-        res = res._replace(trace=full)
     return res, nu
 
 
@@ -182,4 +159,4 @@ from sagecal_tpu.obs.perf import instrumented_jit  # noqa: E402
 
 robust_lm_solve_jit = instrumented_jit(
     robust_lm_solve, name="robust_lm_solve",
-    static_argnames=("em_iters", "collect_trace", "collect_quality"))
+    static_argnames=("em_iters", "collect_quality"))

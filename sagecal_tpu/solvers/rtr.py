@@ -87,10 +87,8 @@ class RTRResult(NamedTuple):
     p: jax.Array  # (nchunk, 8N)
     cost0: jax.Array  # (nchunk,)
     cost: jax.Array  # (nchunk,)
-    # per-iteration IterTrace (obs.records) when collect_trace=True, else
-    # None — an empty pytree, so the jitted output signature is unchanged
-    trace: Optional[tuple] = None
-    # SolveQuality (ops.quality) when collect_quality=True, same contract
+    # SolveQuality (ops.quality) when collect_quality=True, else None —
+    # an empty pytree, so the jitted output signature is unchanged
     quality: Optional[tuple] = None
     # TRSteps summed over chunks (and robust rounds)
     steps: Optional[TRSteps] = None
@@ -303,7 +301,7 @@ def _tcg(x, grad, Delta, hess, cfg: RTRConfig):
 
 def _rtr_single(
     vis, coh, rowmask, ant_p, ant_q, x0, cfg: RTRConfig, sqrt_w, itmax_dyn=None,
-    admm=None, collect_trace: bool = False,
+    admm=None,
 ):
     """``itmax_dyn``: optional traced base iteration budget; the RSD/TR
     bounds become min(static, dyn+5)/min(static, dyn+10), matching the
@@ -372,10 +370,6 @@ def _rtr_single(
     Delta0 = Delta_bar * 0.125
     rho_reg0 = fx * 1e-6
 
-    from sagecal_tpu.obs.records import init_trace, write_trace
-
-    trace0 = init_trace(cfg.itmax_rtr, (), fx.real.dtype) if collect_trace else None
-
     def tr_cond(s):
         return (s["k"] < rtr_bound) & (~s["stop"])
 
@@ -405,26 +399,13 @@ def _rtr_single(
         x1 = jnp.where(accept, x_prop, x)
         fx1 = jnp.where(accept, fx_prop, fx)
         gnorm = jnp.sqrt(_g(g, g))
-        st = dict(
+        return dict(
             k=s["k"] + 1, x=x1, fx=fx1, Delta=Delta_new,
             stop=gnorm < cfg.epsilon, cg=s["cg"] + cg_j.astype(jnp.int32),
         )
-        if collect_trace:
-            # ls_evals records the inner truncated-CG iteration count —
-            # the TR analog of line-search cost evaluations
-            st["trace"] = write_trace(
-                s["trace"], s["k"],
-                cost=fx1,
-                grad_norm=gnorm,
-                step=jnp.sqrt(jnp.maximum(_g(eta, eta), 0.0)),
-                ls_evals=cg_j.astype(fx1.dtype),
-            )
-        return st
 
     state0 = dict(k=jnp.asarray(0, jnp.int32), x=x, fx=fx, Delta=Delta0,
                   stop=jnp.asarray(False), cg=jnp.asarray(0, jnp.int32))
-    if collect_trace:
-        state0["trace"] = trace0
     with jax.named_scope("tr"):
         out = jax.lax.while_loop(tr_cond, tr_body, match_vma(state0, x))
     steps = TRSteps(loops=jnp.ones((), jnp.int32), tr=out["k"],
@@ -434,13 +415,12 @@ def _rtr_single(
     # guard: never return something worse than the input
     better = out["fx"] <= fx0
     xf = jnp.where(better, out["x"], x0)
-    return (xf, fx0, jnp.where(better, out["fx"], fx0), out.get("trace"),
-            steps)
+    return xf, fx0, jnp.where(better, out["fx"], fx0), steps
 
 
 def _nsd_single(
     vis, coh, rowmask, ant_p, ant_q, x0, itmax, sqrt_w, itmax_dyn=None,
-    admm=None, collect_trace: bool = False,
+    admm=None,
 ):
     """Nesterov accelerated manifold descent
     (nsd_solve_nocuda_robust, rtr_solve_robust.c:1878-2090).
@@ -468,7 +448,6 @@ def _nsd_single(
     def body(carry, i):
         x, z, g, t, theta, done = carry
         done = done | (i >= bound)
-        active = ~done
         x_prop = x
         z_prop = z
         x1 = z - t * g
@@ -494,17 +473,11 @@ def _nsd_single(
             keep(x, x1), keep(z, z1), keep(g, g1), keep(t, t1),
             keep(theta, theta1), done2,
         )
-        if not collect_trace:
-            return carry1, None
-        # per-iteration telemetry costs one extra cost eval per step —
-        # paid only in collect_trace builds (static gate)
-        nanv = jnp.asarray(jnp.nan, t.dtype)
-        mark = lambda v: jnp.where(active, v, nanv)
-        return carry1, (mark(cost_c(carry1[0])), mark(gn), mark(t))
+        return carry1, None
 
     from sagecal_tpu.utils.platform import match_vma
 
-    (x, _, _, _, _, _), ys = jax.lax.scan(
+    (x, _, _, _, _, _), _ = jax.lax.scan(
         body,
         match_vma(
             (x0, x0, g0, t0, jnp.asarray(1.0, t0.dtype), jnp.asarray(False)),
@@ -512,20 +485,9 @@ def _nsd_single(
         ),
         jnp.arange(itmax),
     )
-    if collect_trace:
-        from sagecal_tpu.obs.records import IterTrace
-
-        costs, gns, ts = ys
-        trace = IterTrace(
-            cost=costs, grad_norm=gns, step=ts,
-            ls_evals=jnp.zeros_like(costs),
-            nu=jnp.full((itmax,), jnp.nan, costs.dtype),
-        )
-    else:
-        trace = None
     fx = cost_c(x)
     better = fx <= fx0
-    return (jnp.where(better, x, x0), fx0, jnp.where(better, fx, fx0), trace,
+    return (jnp.where(better, x, x0), fx0, jnp.where(better, fx, fx0),
             no_tr_steps())
 
 
@@ -577,7 +539,7 @@ def _chunked(solver):
                     admm=(y_c, bz_c, r_c), **kwargs,
                 )
 
-            xf, c0, c1, tr, steps = jax.vmap(lane)(
+            xf, c0, c1, steps = jax.vmap(lane)(
                 jnp.arange(nchunk), x0, Yc, BZc, rho)
         else:
 
@@ -587,12 +549,8 @@ def _chunked(solver):
                     vis, coh, rowmask, ant_p, ant_q, x0_c, *args, **kwargs
                 )
 
-            xf, c0, c1, tr, steps = jax.vmap(lane)(jnp.arange(nchunk), x0)
-        if tr is not None:
-            # vmapped per-lane traces are (nchunk, itmax); present them
-            # iteration-major like the LM trace: (itmax, nchunk)
-            tr = jax.tree_util.tree_map(lambda a: jnp.moveaxis(a, 0, 1), tr)
-        return RTRResult(p=jones_to_params(xf), cost0=c0, cost=c1, trace=tr,
+            xf, c0, c1, steps = jax.vmap(lane)(jnp.arange(nchunk), x0)
+        return RTRResult(p=jones_to_params(xf), cost0=c0, cost=c1,
                          steps=sum_tr_steps(steps))
 
     return run
@@ -605,7 +563,6 @@ def rtr_solve(
     sqrt_weights: Optional[jax.Array] = None,
     itmax_dynamic=None,
     admm_y=None, admm_bz=None, admm_rho=None,
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ) -> RTRResult:
     """Batched-over-chunks RTR solve (``rtr_solve_nocuda``, Dirac.h:1132).
@@ -623,7 +580,6 @@ def rtr_solve(
     out = _chunked(_rtr_single)(
         vis, coh, mask, ant_p, ant_q, chunk_map, p0, config, sqrt_weights,
         itmax_dynamic, admm_y=admm_y, admm_bz=admm_bz, admm_rho=admm_rho,
-        collect_trace=collect_trace,
     )
     if collect_quality:
         out = out._replace(quality=_quality_of(
@@ -639,7 +595,6 @@ def nsd_solve(
     sqrt_weights: Optional[jax.Array] = None,
     itmax_dynamic=None,
     admm_y=None, admm_bz=None, admm_rho=None,
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ) -> RTRResult:
     """Batched Nesterov steepest descent (``nsd_solve_nocuda_robust``,
@@ -649,7 +604,6 @@ def nsd_solve(
     out = _chunked(_nsd_single)(
         vis, coh, mask, ant_p, ant_q, chunk_map, p0, itmax, sqrt_weights,
         itmax_dynamic, admm_y=admm_y, admm_bz=admm_bz, admm_rho=admm_rho,
-        collect_trace=collect_trace,
     )
     if collect_quality:
         out = out._replace(quality=_quality_of(
@@ -692,7 +646,6 @@ def rtr_solve_robust(
     em_iters: int = 2,
     itmax_dynamic=None,
     admm_y=None, admm_bz=None, admm_rho=None,
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ):
     """Student's-t EM wrapping RTR (``rtr_solve_nocuda_robust``,
@@ -719,25 +672,16 @@ def rtr_solve_robust(
             vis, coh, mask, ant_p, ant_q, chunk_map, p, config,
             sqrt_weights=sqrt_w, itmax_dynamic=itmax_dynamic,
             admm_y=admm_y, admm_bz=admm_bz, admm_rho=admm_rho,
-            collect_trace=collect_trace,
         )
-        ys = (out.cost0, out.cost, out.steps)
-        if collect_trace:
-            tr = out.trace._replace(
-                nu=jnp.broadcast_to(nu1, out.trace.nu.shape).astype(
-                    out.trace.nu.dtype)
-            )
-            ys = ys + (tr,)
-        return (out.p, nu1), ys
+        return (out.p, nu1), (out.cost0, out.cost, out.steps)
 
     from sagecal_tpu.utils.platform import match_vma
 
-    (p, nu), ys = jax.lax.scan(
+    (p, nu), (c0s, c1s, steps) = jax.lax.scan(
         em, match_vma((p0, jnp.asarray(nu0, p0.dtype)), p0), None,
         length=em_iters
     )
-    c0s, c1s, steps = ys[0], ys[1], sum_tr_steps(ys[2])
-    trace = ys[3] if collect_trace else None  # (em_iters, itmax, nchunk)
+    steps = sum_tr_steps(steps)
     # re-estimate nu from the FINAL solution (the reference updates the
     # weights/nu once more after the loop, rtr_solve_robust.c:1625)
     sqrt_w_f, nu = _robust_weights_and_nu(
@@ -748,7 +692,7 @@ def rtr_solve_robust(
         quality = _quality_of(
             p, vis, coh, mask, ant_p, ant_q, chunk_map,
             sqrt_w=sqrt_w_f, nu=nu)
-    return RTRResult(p=p, cost0=c0s[0], cost=c1s[-1], trace=trace,
+    return RTRResult(p=p, cost0=c0s[0], cost=c1s[-1],
                      quality=quality, steps=steps), nu
 
 
@@ -760,7 +704,6 @@ def nsd_solve_robust(
     em_iters: int = 2,
     itmax_dynamic=None,
     admm_y=None, admm_bz=None, admm_rho=None,
-    collect_trace: bool = False,
     collect_quality: bool = False,
 ):
     """Robust Nesterov descent (``nsd_solve_nocuda_robust``,
@@ -780,25 +723,16 @@ def nsd_solve_robust(
             vis, coh, mask, ant_p, ant_q, chunk_map, p, itmax,
             sqrt_weights=sqrt_w, itmax_dynamic=itmax_dynamic,
             admm_y=admm_y, admm_bz=admm_bz, admm_rho=admm_rho,
-            collect_trace=collect_trace,
         )
-        ys = (out.cost0, out.cost, out.steps)
-        if collect_trace:
-            tr = out.trace._replace(
-                nu=jnp.broadcast_to(nu1, out.trace.nu.shape).astype(
-                    out.trace.nu.dtype)
-            )
-            ys = ys + (tr,)
-        return (out.p, nu1), ys
+        return (out.p, nu1), (out.cost0, out.cost, out.steps)
 
     from sagecal_tpu.utils.platform import match_vma
 
-    (p, nu), ys = jax.lax.scan(
+    (p, nu), (c0s, c1s, steps) = jax.lax.scan(
         em, match_vma((p0, jnp.asarray(nu0, p0.dtype)), p0), None,
         length=em_iters
     )
-    c0s, c1s, steps = ys[0], ys[1], sum_tr_steps(ys[2])
-    trace = ys[3] if collect_trace else None  # (em_iters, itmax, nchunk)
+    steps = sum_tr_steps(steps)
     # final-solution nu re-estimate (rtr_solve_robust.c:2104)
     sqrt_w_f, nu = _robust_weights_and_nu(
         vis, coh, mask, ant_p, ant_q, chunk_map, p, nu, nulow, nuhigh
@@ -808,7 +742,7 @@ def nsd_solve_robust(
         quality = _quality_of(
             p, vis, coh, mask, ant_p, ant_q, chunk_map,
             sqrt_w=sqrt_w_f, nu=nu)
-    return RTRResult(p=p, cost0=c0s[0], cost=c1s[-1], trace=trace,
+    return RTRResult(p=p, cost0=c0s[0], cost=c1s[-1],
                      quality=quality, steps=steps), nu
 
 
@@ -817,10 +751,10 @@ from sagecal_tpu.obs.perf import instrumented_jit  # noqa: E402
 
 rtr_solve_jit = instrumented_jit(
     rtr_solve, name="rtr_solve",
-    static_argnames=("collect_trace", "collect_quality"))
+    static_argnames=("collect_quality",))
 nsd_solve_jit = instrumented_jit(
     nsd_solve, name="nsd_solve",
-    static_argnames=("itmax", "collect_trace", "collect_quality"))
+    static_argnames=("itmax", "collect_quality"))
 rtr_solve_robust_jit = instrumented_jit(
     rtr_solve_robust, name="rtr_solve_robust",
-    static_argnames=("em_iters", "collect_trace", "collect_quality"))
+    static_argnames=("em_iters", "collect_quality"))

@@ -101,16 +101,11 @@ class SageConfig:
     # intentional compile-time/runtime tradeoff: raise it if profiling
     # shows clusters exhausting their dynamic budget.
     iter_budget_cap: int = struct.field(pytree_node=False, default=3)
-    # Collect per-iteration solver telemetry (obs.records.IterTrace) from
-    # every per-cluster solve and the joint LBFGS, returned in
-    # SageResult.telemetry.  Static: off builds the exact same jaxpr as
-    # before (telemetry slots are None = empty pytrees).
-    collect_telemetry: bool = struct.field(pytree_node=False, default=False)
     # Collect fixed-shape solution-quality side outputs (ops/quality.py):
     # per-cluster SolveQuality from the FINAL EM pass's solves (leading
     # cluster axis) plus a whole-solution bundle at the returned
-    # parameters, in SageResult.quality.  Same static-gate contract as
-    # collect_telemetry: off builds the identical jaxpr.
+    # parameters, in SageResult.quality.  Static: off builds the
+    # identical jaxpr (the quality slots are None = empty pytrees).
     collect_quality: bool = struct.field(pytree_node=False, default=False)
 
 
@@ -135,15 +130,11 @@ class SageResult(NamedTuple):
     res_1: jax.Array  # final residual norm / n
     mean_nu: jax.Array
     diverged: jax.Array  # bool, res_1 > res_0 (the reference's -1 return)
-    # {"em": tuple of per-EM-pass IterTrace pytrees (leading cluster
-    # axis), "lbfgs": joint-LBFGS IterTrace} when
-    # config.collect_telemetry, else None (empty pytree — jitted output
-    # signature unchanged)
-    telemetry: Optional[dict] = None
     # {"em": SolveQuality stacked over clusters from the final EM pass,
     # "final": whole-solution SolveQuality (chi^2 attribution of the
     # full residual at the returned p + gain health)} when
-    # config.collect_quality, else None (same empty-pytree contract)
+    # config.collect_quality, else None (empty pytree — jitted output
+    # signature unchanged)
     quality: Optional[dict] = None
     # int32 solver work of the solve, always returned: {"tr_loops",
     # "tr_steps", "tcg_steps", "tr_capped", "rsd_steps", "rsd_moved"}
@@ -605,7 +596,7 @@ def _em_phase(
 ):
     """The SAGE expectation passes of :func:`sagefit` — per-cluster
     solves and nu estimation, NO joint LBFGS and no finalization.
-    Returns ``(p, mean_nu, res_0, em_traces, em_quality, em_steps)``,
+    Returns ``(p, mean_nu, res_0, em_quality, em_steps)``,
     ``em_steps`` the :class:`~sagecal_tpu.solvers.rtr.TRSteps` summed
     over passes and clusters.  Factored out so
     :func:`sagefit_batched_fused` can vmap the per-cluster EM machinery
@@ -631,7 +622,6 @@ def _em_phase(
         c1 = jnp.sum(res.cost)
         return jnp.where(c0 > 0.0, jnp.maximum((c0 - c1) / c0, 0.0), 0.0)
 
-    collect = config.collect_telemetry
     collect_q = config.collect_quality
 
     def em_iteration(p_all, nerr, nus_in, weighted, em_idx, key):
@@ -645,8 +635,6 @@ def _em_phase(
             steps = getattr(res, "steps", None)
             aux = (_nerr_of(res), nu_k,
                    no_tr_steps() if steps is None else steps)
-            if collect:
-                aux = aux + (res.trace,)
             if want_q:
                 aux = aux + (res.quality,)
             return aux
@@ -682,7 +670,7 @@ def _em_phase(
                     RTRConfig(itmax_rsd=iter_cap + 5,
                               itmax_rtr=iter_cap + 10),
                     itmax_dynamic=itermax,
-                    collect_trace=collect, collect_quality=want_q,
+                    collect_quality=want_q,
                 )
                 return res.p, _aux_of(res, jnp.asarray(config.nulow, p_all.dtype))
             if mode == SM_RTR_OSRLM_RLBFGS:
@@ -697,7 +685,7 @@ def _em_phase(
                     nu0=nu_prev, nulow=config.nulow, nuhigh=config.nuhigh,
                     em_iters=config.em_rounds_robust,
                     itmax_dynamic=itermax,
-                    collect_trace=collect, collect_quality=want_q,
+                    collect_quality=want_q,
                 )
                 return res.p, _aux_of(res, nu_k.astype(p_all.dtype))
             if mode == SM_NSD_RLBFGS:
@@ -710,7 +698,7 @@ def _em_phase(
                     nu0=nu_prev, nulow=config.nulow, nuhigh=config.nuhigh,
                     em_iters=config.em_rounds_robust,
                     itmax_dynamic=itermax,
-                    collect_trace=collect, collect_quality=want_q,
+                    collect_quality=want_q,
                 )
                 return res.p, _aux_of(res, nu_k.astype(p_all.dtype))
             if use_robust:
@@ -719,20 +707,18 @@ def _em_phase(
                     nu0=config.nulow, nulow=config.nulow, nuhigh=config.nuhigh,
                     em_iters=config.em_rounds_robust,
                     config=LMConfig(itmax=config.max_iter),
-                    collect_trace=collect, collect_quality=want_q,
+                    collect_quality=want_q,
                 )
             elif use_os:
                 res = os_lm_solve(
                     xeff, coh_k, data.mask, data.ant_p, data.ant_q, cmap_k, p_k,
-                    lmcfg, nsubsets=2, key=key_k, collect_trace=collect,
-                    collect_quality=want_q,
+                    lmcfg, nsubsets=2, key=key_k, collect_quality=want_q,
                 )
                 nu_k = jnp.asarray(config.nulow, p_all.dtype)
             else:
                 res = lm_solve(
                     xeff, coh_k, data.mask, data.ant_p, data.ant_q, cmap_k, p_k,
-                    lmcfg, itmax_dynamic=itermax, collect_trace=collect,
-                    collect_quality=want_q,
+                    lmcfg, itmax_dynamic=itermax, collect_quality=want_q,
                 )
                 nu_k = jnp.asarray(config.nulow, p_all.dtype)
             return res.p, _aux_of(res, nu_k)
@@ -742,32 +728,28 @@ def _em_phase(
         )
         nerr_new, nus = aux[0], aux[1]
         steps = sum_tr_steps(aux[2])
-        tr = aux[3] if collect else None  # IterTrace, leading cluster axis
         # SolveQuality with leading cluster axis on the final pass
         qual = aux[-1] if want_q else None
         total = jnp.sum(nerr_new)
         nerr_norm = jnp.where(total > 0.0, nerr_new / total, nerr_new)
-        return p_new, nerr_norm, nus, key, tr, qual, steps
+        return p_new, nerr_norm, nus, key, qual, steps
 
     p = p0
     nerr = jnp.zeros((M,), p0.dtype)
     weighted = jnp.asarray(False)
     nus = jnp.full((M,), config.nulow, p0.dtype)
-    em_traces = []
     em_quality = None
     em_steps = no_tr_steps()
     for em in range(config.max_emiter):
-        p, nerr, nus, key, tr, qual, steps = em_iteration(
+        p, nerr, nus, key, qual, steps = em_iteration(
             p, nerr, nus, weighted, em, key)
         em_steps = TRSteps(*(a + b for a, b in zip(em_steps, steps)))
-        if collect:
-            em_traces.append(tr)
         if qual is not None:
             em_quality = qual
         if config.randomize:
             weighted = ~weighted
     mean_nu = jnp.clip(jnp.mean(nus), config.nulow, config.nuhigh)
-    return p, mean_nu, res_0, em_traces, em_quality, em_steps
+    return p, mean_nu, res_0, em_quality, em_steps
 
 
 @scoped("finalize")
@@ -778,17 +760,14 @@ def _finalize(
     res_0: jax.Array,
     mean_nu: jax.Array,
     config: SageConfig,
-    lbfgs_trace,
-    em_traces,
     em_quality,
     em_steps,
     lbfgs_iters,
 ) -> SageResult:
-    """Final full-model residual plus telemetry/quality bundling — the
+    """Final full-model residual plus quality and step bundling — the
     tail of :func:`sagefit` after the joint LBFGS, shared with the
     batched fused driver (vmapped per lane there)."""
     robust = config.solver_mode in _ROBUST_MODES
-    collect = config.collect_telemetry
     collect_q = config.collect_quality
     F, rows = data.vis.shape[-3], data.vis.shape[-1]
     nreal = rows * F * 8
@@ -796,9 +775,6 @@ def _finalize(
 
     full1 = predict_full_model(p, cdata, data)
     res_1 = _res_norm(data.vis - full1, data.mask, nreal)
-    telemetry = (
-        {"em": tuple(em_traces), "lbfgs": lbfgs_trace} if collect else None
-    )
     quality = None
     if collect_q:
         # whole-solution bundle: chi^2 of the FULL residual (all cluster
@@ -832,7 +808,7 @@ def _finalize(
              "lbfgs_iters": jnp.asarray(lbfgs_iters, jnp.int32)}
     return SageResult(
         p=p, res_0=res_0, res_1=res_1, mean_nu=mean_nu,
-        diverged=res_1 > res_0, telemetry=telemetry, quality=quality,
+        diverged=res_1 > res_0, quality=quality,
         steps=steps,
     )
 
@@ -848,22 +824,20 @@ def sagefit(
     """One tile's SAGE calibration.  ``p0``: (M, nchunk_max, 8N)."""
     if key is None:
         key = jax.random.PRNGKey(0)
-    p, mean_nu, res_0, em_traces, em_quality, em_steps = _em_phase(
+    p, mean_nu, res_0, em_quality, em_steps = _em_phase(
         data, cdata, p0, config, key)
     with jax.named_scope("lbfgs"):
-        p, lbfgs_trace, lbfgs_iters = _joint_lbfgs(
-            data, cdata, p, mean_nu, config)
-    return _finalize(data, cdata, p, res_0, mean_nu, config, lbfgs_trace,
-                     em_traces, em_quality, em_steps, lbfgs_iters)
+        p, lbfgs_iters = _joint_lbfgs(data, cdata, p, mean_nu, config)
+    return _finalize(data, cdata, p, res_0, mean_nu, config, em_quality,
+                     em_steps, lbfgs_iters)
 
 
 def _joint_lbfgs(data, cdata, p, mean_nu, config):
     """The joint LBFGS over all parameters (lmfit.c:1019-1037) of
-    :func:`sagefit`.  Returns ``(p, lbfgs_trace, iterations)``."""
+    :func:`sagefit`.  Returns ``(p, iterations)``."""
     M, nchunk_max, n8 = p.shape
     robust = config.solver_mode in _ROBUST_MODES
-    collect = config.collect_telemetry
-    lbfgs_trace, iters = None, 0
+    iters = 0
     if config.max_lbfgs > 0:
         pflat0 = p.reshape(-1)
 
@@ -892,15 +866,15 @@ def _joint_lbfgs(data, cdata, p, mean_nu, config):
                 itmax=config.max_lbfgs, M=config.lbfgs_m,
             )
             p = fitb.p.reshape(M, nchunk_max, n8)
-            iters = fitb.iterations  # no trace on the bounded path
+            iters = fitb.iterations
         else:
             fit = lbfgs_fit(
                 cost_fn, None, pflat0, itmax=config.max_lbfgs,
-                M=config.lbfgs_m, collect_trace=collect,
+                M=config.lbfgs_m,
             )
             p = fit.p.reshape(M, nchunk_max, n8)
-            lbfgs_trace, iters = fit.trace, fit.iterations
-    return p, lbfgs_trace, iters
+            iters = fit.iterations
+    return p, iters
 
 
 @true_f32
@@ -941,18 +915,17 @@ def sagefit_batched_fused(
             "sagefit_batched_fused requires nchunk_max == 1 (the batched "
             "kernel has no hybrid-chunk selection); use the vmapped path"
         )
-    if config.param_bound > 0.0 or config.collect_telemetry:
+    if config.param_bound > 0.0:
         raise ValueError(
-            "batched fused path supports neither param_bound nor "
-            "telemetry traces; use the vmapped path"
+            "batched fused path does not support param_bound; use the "
+            "vmapped path"
         )
     if keys is None:
         keys = jax.random.split(jax.random.PRNGKey(0), B)
     robust = config.solver_mode in _ROBUST_MODES
 
-    # quality side outputs (collect_quality) vmap straight through —
-    # only telemetry traces are excluded (guarded above)
-    p_b, mean_nu_b, res_0_b, _, em_q, em_steps_b = jax.vmap(
+    # quality side outputs (collect_quality) vmap straight through
+    p_b, mean_nu_b, res_0_b, em_q, em_steps_b = jax.vmap(
         lambda d, c, p, k: _em_phase(d, c, p, config, k)
     )(data, cdata, p0, keys)
 
@@ -974,7 +947,7 @@ def sagefit_batched_fused(
 
     return jax.vmap(
         lambda d, c, p, r0, mn, eq, st, it: _finalize(
-            d, c, p, r0, mn, config, None, [], eq, st, it)
+            d, c, p, r0, mn, config, eq, st, it)
     )(data, cdata, p_b, res_0_b, mean_nu_b, em_q, em_steps_b, iters_b)
 
 
@@ -1004,9 +977,8 @@ def sagefit_packed(
 
     The re/im split was made for an earlier TPU runtime that could not
     move complex arrays between host and device.  On the v5e with
-    today's runtime complex64 crosses both ways (PR 21, chip_smoke
-    probe), so the split is no longer needed; its removal is queued
-    (ROADMAP C2b).
+    today's runtime complex64 crosses both ways, so the split is no
+    longer needed; its removal is queued (ROADMAP C3).
 
     Matmul precision comes from the ``true_f32`` decorator on
     ``sagefit`` and every other solver entry (utils/precision.py)."""

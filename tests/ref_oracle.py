@@ -160,8 +160,8 @@ def ref_bfgsfit(
     max_lbfgs=20, lbfgs_m=7, solver_mode=2, mean_nu=5.0,
 ):
     """Run the reference ``bfgsfit_visibilities`` (Dirac.h:1683,
-    lmfit.c:1126): the joint LBFGS-only multi-cluster fit — the same
-    work bench.py times per iteration (full-model predict + gradient
+    lmfit.c:1126): the joint LBFGS-only multi-cluster fit — the
+    joint LBFGS's per-iteration work (full-model predict + gradient
     over all 8*N*M parameters; robust Student's-t cost when
     solver_mode is one of the R-LBFGS modes).  Shapes as in
     :func:`ref_sagefit`.  Returns (jones, res_0, res_1, retval)."""

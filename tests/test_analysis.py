@@ -372,7 +372,7 @@ class TestContracts:
         monkeypatch.delenv("SAGECAL_CHECKIFY", raising=False)
         args = _lm_problem()
         ref_fn = jax.jit(
-            lm_solve, static_argnames=("collect_trace", "collect_quality"))
+            lm_solve, static_argnames=("collect_quality",))
         ref = ref_fn(*args)
         out = lm_solve_jit(*args)
         # bit-identical, not allclose: the contract layer must not

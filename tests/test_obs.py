@@ -1,14 +1,10 @@
-"""Telemetry subsystem tests: metrics registry semantics, fixed-shape
-per-iteration solver traces under jit, JSONL event-log round trips, run
-manifests, ADMM residual telemetry vs pure-python references, and the
-zero-cost-when-disabled regression (telemetry off must not change any
-solver's jitted output signature)."""
+"""Telemetry subsystem tests: metrics registry semantics, JSONL
+event-log round trips, run manifests, ADMM residual telemetry vs
+pure-python references, and the diag CLI over the event log."""
 
 import json
 import math
-import os
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,13 +15,6 @@ from sagecal_tpu.obs.events import (
     default_event_log,
     read_events,
     validate_manifest,
-)
-from sagecal_tpu.obs.records import (
-    IterTrace,
-    init_trace,
-    sage_convergence_records,
-    trace_to_host,
-    write_trace,
 )
 from sagecal_tpu.obs.registry import (
     MetricsRegistry,
@@ -110,141 +99,6 @@ class TestRegistry:
 
 
 # ---------------------------------------------------------------------------
-# fixed-shape iteration traces
-# ---------------------------------------------------------------------------
-
-
-class TestIterTrace:
-    def test_init_and_write_under_jit(self):
-        def run(i, c):
-            tr = init_trace(5, (3,))
-            return write_trace(tr, i, cost=c, grad_norm=2 * c,
-                               ls_evals=jnp.ones((3,), jnp.float32),
-                               nu=jnp.float32(2.0))
-
-        tr = jax.jit(run)(jnp.int32(2), jnp.full((3,), 7.0, jnp.float32))
-        cost = np.asarray(tr.cost)
-        assert cost.shape == (5, 3)
-        np.testing.assert_allclose(cost[2], 7.0)
-        assert np.isnan(cost[[0, 1, 3, 4]]).all()
-        np.testing.assert_allclose(np.asarray(tr.grad_norm)[2], 14.0)
-        np.testing.assert_allclose(np.asarray(tr.ls_evals)[2], 1.0)
-        assert np.asarray(tr.ls_evals)[0].sum() == 0.0  # zeros, not NaN
-        assert float(np.asarray(tr.nu)[2]) == 2.0
-
-    def test_trace_to_host(self):
-        tr = init_trace(2, ())
-        d = trace_to_host(tr)
-        assert set(d) == set(IterTrace._fields)
-        assert len(d["cost"]) == 2
-        assert trace_to_host(None) == {}
-
-
-def _synthetic_solver_arrays(seed=0, N=3, rows=12, F=1, M=2):
-    """Tiny calibration problem: identical to what the solver smoke tests
-    use — small enough that LM/LBFGS compile in seconds on CPU."""
-    rng = np.random.default_rng(seed)
-    ant_p = jnp.asarray(np.repeat(np.arange(N), rows // N)[:rows] % N)
-    ant_q = (ant_p + 1) % N
-    coh = jnp.asarray(
-        (rng.normal(size=(F, 4, rows))
-         + 1j * rng.normal(size=(F, 4, rows))).astype(np.complex64))
-    vis = coh + 0.01 * jnp.asarray(
-        (rng.normal(size=(F, 4, rows))
-         + 1j * rng.normal(size=(F, 4, rows))).astype(np.complex64))
-    mask = jnp.ones((F, rows), jnp.float32)
-    cm = jnp.asarray((np.arange(rows) % M).astype(np.int32))
-    p0 = jnp.asarray(
-        np.tile(np.array([1, 0, 0, 0, 0, 0, 1, 0] * N, np.float32), (M, 1)))
-    return vis, coh, mask, ant_p, ant_q, cm, p0
-
-
-class TestSolverTraces:
-    def test_lbfgs_trace_and_zero_cost_off(self):
-        from sagecal_tpu.solvers.lbfgs import lbfgs_fit
-
-        def cost_fn(x):
-            return jnp.sum((x - 1.0) ** 2) + 0.1 * jnp.sum(x ** 4)
-
-        x0 = jnp.zeros((6,), jnp.float32)
-        off = jax.jit(lambda x: lbfgs_fit(cost_fn, None, x, itmax=8))(x0)
-        on = jax.jit(
-            lambda x: lbfgs_fit(cost_fn, None, x, itmax=8, collect_trace=True)
-        )(x0)
-        assert off.trace is None
-        # trace rides along as extra outputs; base fields are bit-identical
-        n_off = len(jax.tree_util.tree_leaves(off))
-        assert len(jax.tree_util.tree_leaves(on)) == n_off + 5
-        np.testing.assert_allclose(np.asarray(on.p), np.asarray(off.p))
-        it = int(on.iterations)
-        assert it > 0
-        cost = np.asarray(on.trace.cost)
-        assert cost.shape == (8,)
-        assert np.all(np.isfinite(cost[:it]))
-        # monotone-ish: the line search never accepts an increase here
-        assert cost[it - 1] <= cost[0]
-        assert np.all(np.asarray(on.trace.ls_evals)[:it] >= 1)
-
-    def test_lm_trace_shapes_and_zero_cost_off(self):
-        from sagecal_tpu.solvers.lm import LMConfig, lm_solve
-
-        vis, coh, mask, ant_p, ant_q, cm, p0 = _synthetic_solver_arrays()
-        cfg = LMConfig(itmax=4)
-        off = jax.jit(
-            lambda p: lm_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg)
-        )(p0)
-        on = jax.jit(
-            lambda p: lm_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg,
-                               collect_trace=True)
-        )(p0)
-        assert off.trace is None
-        assert len(jax.tree_util.tree_leaves(off)) == 4  # p, cost0, cost, it
-        assert len(jax.tree_util.tree_leaves(on)) == 4 + 5
-        assert on.trace.cost.shape == (4, 2)  # (itmax, nchunk)
-        np.testing.assert_allclose(np.asarray(on.p), np.asarray(off.p))
-        cost = np.asarray(on.trace.cost)
-        it = int(on.iterations)
-        assert np.all(np.isfinite(cost[:it]))
-        # final traced cost row matches the solver's reported final cost
-        np.testing.assert_allclose(
-            cost[it - 1], np.asarray(on.cost), rtol=1e-5)
-
-    @pytest.mark.slow
-    def test_rtr_and_nsd_traces(self):
-        from sagecal_tpu.solvers.rtr import (
-            RTRConfig, nsd_solve, rtr_solve, rtr_solve_robust,
-        )
-
-        vis, coh, mask, ant_p, ant_q, cm, p0 = _synthetic_solver_arrays()
-        cfg = RTRConfig(itmax_rsd=1, itmax_rtr=3, max_inner=3)
-        off = jax.jit(
-            lambda p: rtr_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg)
-        )(p0)
-        assert off.trace is None
-        # p, cost0, cost and the six TRSteps counts
-        assert len(jax.tree_util.tree_leaves(off)) == 9
-        on = jax.jit(
-            lambda p: rtr_solve(vis, coh, mask, ant_p, ant_q, cm, p, cfg,
-                                collect_trace=True)
-        )(p0)
-        assert on.trace.cost.shape == (3, 2)
-        np.testing.assert_allclose(np.asarray(on.p), np.asarray(off.p))
-
-        n_on = jax.jit(
-            lambda p: nsd_solve(vis, coh, mask, ant_p, ant_q, cm, p, 4,
-                                collect_trace=True)
-        )(p0)
-        assert n_on.trace.cost.shape == (4, 2)
-
-        rr, _nu = jax.jit(
-            lambda p: rtr_solve_robust(vis, coh, mask, ant_p, ant_q, cm, p,
-                                       cfg, em_iters=2, collect_trace=True)
-        )(p0)
-        assert rr.trace.cost.shape == (2, 3, 2)  # (em, itmax, nchunk)
-        assert np.all(np.isfinite(np.asarray(rr.trace.nu)))
-
-
-# ---------------------------------------------------------------------------
 # event log + run manifest
 # ---------------------------------------------------------------------------
 
@@ -318,81 +172,6 @@ class TestEvents:
             log.emit("x")
             log.close()
         assert read_events(str(tmp_path / "e.jsonl"))[0]["type"] == "x"
-
-
-# ---------------------------------------------------------------------------
-# host-side convergence record flattening
-# ---------------------------------------------------------------------------
-
-
-def _trace(cost, grad, nu=None):
-    cost = np.asarray(cost, np.float64)
-    nu = np.full(cost.shape[:-1], 2.0) if nu is None else np.asarray(nu)
-    return IterTrace(cost=cost, grad_norm=np.asarray(grad, np.float64),
-                     step=np.zeros_like(cost), ls_evals=np.ones_like(cost),
-                     nu=nu)
-
-
-class TestConvergenceRecords:
-    def test_empty(self):
-        assert sage_convergence_records(None) == []
-        assert sage_convergence_records({}) == []
-
-    def test_chunk_reduction_and_nan_filtering(self):
-        nan = np.nan
-        # one pass, 2 clusters, itmax 3, nchunk 2; cluster 0 ran 2 iters
-        cost = [[[1.0, 2.0], [0.5, 1.0], [nan, nan]],
-                [[4.0, nan], [2.0, nan], [1.0, nan]]]
-        grad = [[[3.0, 5.0], [1.0, 2.0], [nan, nan]],
-                [[6.0, nan], [3.0, nan], [1.5, nan]]]
-        tel = {"em": (_trace(cost, grad),), "lbfgs": None}
-        recs = sage_convergence_records(tel)
-        assert len(recs) == 2
-        r0 = recs[0]
-        assert r0["cluster"] == 0 and r0["iterations"] == 2
-        assert r0["cost"] == [3.0, 1.5]      # summed over chunks
-        assert r0["grad_norm"] == [5.0, 2.0]  # max over chunks
-        r1 = recs[1]
-        # cluster 1: chunk 1 never executed (all NaN) but chunk 0 did
-        assert r1["iterations"] == 3
-        assert r1["cost"] == [4.0, 2.0, 1.0]
-        assert r1["grad_norm"] == [6.0, 3.0, 1.5]
-
-    def test_heterogeneous_passes_concatenate(self):
-        nan = np.nan
-        # pass 1: plain (M=1, it=2, nchunk=1); pass 2: robust stack
-        # (M=1, em=2, it=1, nchunk=1) with nu (M=1, em=2, it=1)
-        p1 = _trace([[[2.0], [1.0]]], [[[4.0], [2.0]]])
-        p2 = IterTrace(
-            cost=np.asarray([[[[0.8]], [[0.5]]]]),
-            grad_norm=np.asarray([[[[1.0]], [[0.5]]]]),
-            step=np.zeros((1, 2, 1, 1)),
-            ls_evals=np.ones((1, 2, 1, 1)),
-            nu=np.asarray([[[30.0], [11.0]]]),
-        )
-        recs = sage_convergence_records({"em": (p1, p2), "lbfgs": None})
-        assert len(recs) == 1
-        r = recs[0]
-        assert r["iterations"] == 4
-        assert r["cost"] == [2.0, 1.0, 0.8, 0.5]
-        assert r["nu"] == [2.0, 2.0, 30.0, 11.0]
-
-    def test_lbfgs_record(self):
-        lb = IterTrace(
-            cost=np.asarray([3.0, 1.0, np.nan]),
-            grad_norm=np.asarray([2.0, 0.5, np.nan]),
-            step=np.asarray([0.1, 0.2, np.nan]),
-            ls_evals=np.asarray([1.0, 2.0, 0.0]),
-            nu=np.asarray([np.nan, np.nan, np.nan]),
-        )
-        recs = sage_convergence_records({"em": (), "lbfgs": lb})
-        assert len(recs) == 1
-        r = recs[0]
-        assert r["cluster"] is None and r["solver"] == "lbfgs"
-        assert r["iterations"] == 2
-        assert r["cost"] == [3.0, 1.0]
-        assert r["nu"] == [None, None]  # NaN -> null keeps the JSONL valid
-        json.dumps(recs)
 
 
 # ---------------------------------------------------------------------------
@@ -498,68 +277,6 @@ class TestAdmmMeshTrace:
 
 
 # ---------------------------------------------------------------------------
-# sagefit end-to-end telemetry + zero-cost-off regression
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-class TestSagefitTelemetry:
-    def test_telemetry_shapes_and_identical_solutions(self):
-        from sagecal_tpu.core.types import identity_jones, jones_to_params
-        from sagecal_tpu.io.simulate import (
-            corrupt_and_observe, make_visdata, random_jones,
-        )
-        from sagecal_tpu.ops.rime import point_source_batch
-        from sagecal_tpu.solvers.sage import (
-            SageConfig, build_cluster_data, sagefit,
-        )
-
-        d = make_visdata(nstations=5, tilesz=2, nchan=1, seed=3)
-        rng = np.random.default_rng(3)
-        clusters = []
-        for k in range(2):
-            S = 2
-            ll = (0.03 * (k + 1) * np.cos(np.pi * k)
-                  + 0.005 * rng.standard_normal(S))
-            mm = (0.03 * (k + 1) * np.sin(np.pi * k)
-                  + 0.005 * rng.standard_normal(S))
-            clusters.append(point_source_batch(
-                jnp.asarray(ll, jnp.float32), jnp.asarray(mm, jnp.float32),
-                jnp.asarray(rng.uniform(1.0, 3.0, S), jnp.float32)))
-        J = random_jones(2, 5, seed=4, amp=0.15)
-        obs = corrupt_and_observe(d, clusters, jones=J, noise_sigma=1e-4,
-                                  seed=5)
-        cdata = build_cluster_data(obs, clusters, [1, 1], fdelta=0.0)
-        M, nst = 2, obs.nstations
-        p0 = jnp.broadcast_to(
-            jones_to_params(identity_jones(nst))[None, None],
-            (M, 1, 8 * nst))
-
-        off = sagefit(obs, cdata, p0,
-                      SageConfig(max_emiter=2, max_iter=4, max_lbfgs=4))
-        assert off.telemetry is None
-        # p, res_0, res_1, mean_nu, diverged and the five step counts
-        assert len(jax.tree_util.tree_leaves(off)) == 10
-
-        on = sagefit(obs, cdata, p0,
-                     SageConfig(max_emiter=2, max_iter=4, max_lbfgs=4,
-                                collect_telemetry=True))
-        tel = on.telemetry
-        assert len(tel["em"]) == 2
-        assert tel["em"][0].cost.shape == (M, 4, 1)  # (cluster, it, chunk)
-        assert tel["lbfgs"].cost.shape == (4,)
-        np.testing.assert_allclose(np.asarray(on.p), np.asarray(off.p))
-
-        recs = sage_convergence_records(tel)
-        assert len(recs) == M + 1
-        assert {r["cluster"] for r in recs} == {0, 1, None}
-        for r in recs:
-            assert r["iterations"] >= 1
-            assert all(c is not None for c in r["cost"])
-        json.dumps(recs)
-
-
-# ---------------------------------------------------------------------------
 # diag CLI
 # ---------------------------------------------------------------------------
 
@@ -587,8 +304,6 @@ class TestDiagCli:
 
         p = str(tmp_path / "ev.jsonl")
         with EventLog(p, manifest=RunManifest.collect()) as log:
-            log.emit("cluster_convergence", tile=0, cluster=0,
-                     iterations=2, cost=[4.0, 1.0], grad_norm=[2.0, 0.5])
             log.emit("admm_round", tile=0, primal_res=[0.2, 0.1],
                      dual_res=[0.05, 0.02])
             log.emit("tile_done", tile=0,
@@ -597,9 +312,8 @@ class TestDiagCli:
         assert main(["validate", p]) == 0
         assert main(["events", p]) == 0
         out = capsys.readouterr().out
-        assert "4 events" in out
-        assert "cluster_convergence: 1" in out
-        assert "final cost min=1" in out
+        assert "3 events" in out
+        assert "admm_round: 1" in out
         assert "dual_res max=0.05" in out
         assert "1 done, 2.00s in phases" in out
 
@@ -665,17 +379,6 @@ class TestFullbatchTelemetry:
         assert man["platform"] == "cpu"
         run_id = man["run_id"]
         assert all(e["run_id"] == run_id for e in evs)
-
-        # per-cluster convergence: cost + grad_norm per iteration
-        conv = by_type["cluster_convergence"]
-        clusters = {c["cluster"] for c in conv}
-        assert {0, 1}.issubset(clusters)
-        assert None in clusters  # the joint LBFGS polish record
-        for c in conv:
-            assert c["iterations"] >= 1
-            assert len(c["cost"]) == c["iterations"]
-            assert len(c["grad_norm"]) == c["iterations"]
-            assert all(v is None or np.isfinite(v) for v in c["cost"])
 
         # per-tile phase timings
         tiles = by_type["tile_done"]

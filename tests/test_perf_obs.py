@@ -304,8 +304,8 @@ class TestPerfEventsAndDiag:
         assert rc == 1
 
     def test_note_compile_external_channel(self):
-        # bench.py reports its self-managed AOT compile through
-        # note_compile; it must land in the same aggregates
+        # code that AOT-compiles itself reports through note_compile;
+        # it must land in the same aggregates
         with telemetry(True):
             note_compile("bench_step_fused", 0.5, 2.0, 1e9, 2e8)
         st = perf_stats()["bench_step_fused"]

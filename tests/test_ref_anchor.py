@@ -310,9 +310,8 @@ def test_anchor_two_cluster_ladder_crossing():
 def test_anchor_bfgsfit_joint_lbfgs():
     """``bfgsfit_visibilities`` anchor (lmfit.c:1126): the reference's
     joint LBFGS-only multi-cluster fit vs our joint LBFGS on the same
-    noiseless two-cluster scene — the per-iteration work bench.py
-    times.  Both run Gaussian cost (solver_mode 1 -> lbfgs_fit_wrapper)
-    from identity to deep convergence."""
+    noiseless two-cluster scene.  Both run Gaussian cost (solver_mode 1
+    -> lbfgs_fit_wrapper) from identity to deep convergence."""
     import jax
 
     from sagecal_tpu.solvers.lbfgs import lbfgs_fit

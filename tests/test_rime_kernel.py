@@ -685,7 +685,7 @@ def test_donated_lbfgs_entry_bit_identical_and_consumes_input():
     plain = jax.jit(
         lbfgs_fit,
         static_argnames=("cost_fn", "grad_fn", "itmax", "M", "minibatch",
-                         "collect_trace", "vg_fn"))
+                         "vg_fn"))
     p_ref = jnp.asarray(p0_host)
     r_ref = plain(cost_fn, None, p_ref, itmax=5, M=3)
 

@@ -182,7 +182,7 @@ def _oracle(vis, coh, rowmask, ant_p, ant_q, x0, cfg, itmax_dyn=None):
     with no warm-up of its own: (x, cost, TRSteps, moved, halvings)."""
     x, (moved, halvings) = _scan_rsd(vis, coh, rowmask, ant_p, ant_q, x0,
                                      cfg, itmax_dyn)
-    xf, _, cost, _, steps = _rtr_single(
+    xf, _, cost, steps = _rtr_single(
         vis, coh, rowmask, ant_p, ant_q, x, cfg.replace(itmax_rsd=0), None,
         itmax_dyn)
     return xf, cost, steps, moved, halvings
@@ -276,7 +276,7 @@ class TestRSDStop:
         assert int(res.steps.rsd_moved) == _CFG.itmax_rsd
         for c in range(2):
             rowmask = mask * (cmap == c)[None, :].astype(mask.dtype)
-            x1, _, cost1, _, steps1 = jax.jit(lambda x0: _rtr_single(
+            x1, _, cost1, steps1 = jax.jit(lambda x0: _rtr_single(
                 vis, coh, rowmask, ant_p, ant_q, x0, _CFG, None))(
                 params_to_jones(p0[c]))
             assert int(steps1.rsd) == (1 if c else _CFG.itmax_rsd)

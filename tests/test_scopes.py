@@ -221,25 +221,60 @@ def test_scopes_change_only_metadata(tile, scoped_text, monkeypatch):
 # ---------------------------------------------------------- step counts
 
 
-def test_step_counts_match_the_telemetry_traces(tile):
-    """SageResult.steps, always returned, count what the telemetry build
-    records row by row: one finite trace row per TR step and per LBFGS
-    iteration, the tCG steps in the TR rows' ls_evals."""
+def test_step_counts_match_the_executed_steps(tile, monkeypatch):
+    """SageResult.steps, always returned, count the steps the program
+    runs, as seen from outside its carry: a host callback on every tCG
+    solve (one per TR step, with its inner steps), on every RTR gradient
+    (one per RSD and per TR step) and on every LBFGS direction (one per
+    iteration)."""
+    from sagecal_tpu.solvers import lbfgs, rtr
     from sagecal_tpu.solvers.sage import sagefit
 
+    seen = {"tcg": 0, "cg": 0, "grad": 0, "lbfgs": 0}
+    tcg_plain, make_fns_plain = rtr._tcg, rtr._make_fns
+    two_loop_plain = lbfgs._two_loop_direction
+
+    def bump(name):
+        def callback(_):
+            seen[name] += 1
+        return callback
+
+    def note_tcg(j):
+        seen["tcg"] += int(np.size(j))
+        seen["cg"] += int(np.sum(j))
+
+    def tcg(*args):
+        out = tcg_plain(*args)
+        jax.debug.callback(note_tcg, out[2])
+        return out
+
+    def make_fns(*args, **kwargs):
+        cost_c, grad_fn, hess_fn = make_fns_plain(*args, **kwargs)
+
+        def counted_grad(x, iw):
+            jax.debug.callback(bump("grad"), x)
+            return grad_fn(x, iw)
+        return cost_c, counted_grad, hess_fn
+
+    def two_loop(g, mem):
+        jax.debug.callback(bump("lbfgs"), g)
+        return two_loop_plain(g, mem)
+
+    monkeypatch.setattr(rtr, "_tcg", tcg)
+    monkeypatch.setattr(rtr, "_make_fns", make_fns)
+    monkeypatch.setattr(lbfgs, "_two_loop_direction", two_loop)
+
     data, cdata, p0 = tile
-    cfg = _config(use_fused_predict=False, collect_telemetry=True,
-                  max_lbfgs=4)
+    cfg = _config(use_fused_predict=False, max_lbfgs=4)
+    jax.clear_caches()  # trace anew through the counting wrappers
     res = jax.jit(sagefit, static_argnames=("config",))(
         data, cdata, jnp.asarray(p0), config=cfg)
     steps = {k: int(v) for k, v in res.steps.items()}
-    em = res.telemetry["em"]
-    assert len(em) == cfg.max_emiter
-    cost = np.stack([np.asarray(t.cost) for t in em])
-    ls = np.stack([np.asarray(t.ls_evals) for t in em])
-    finite = np.isfinite(cost)
-    assert steps["tr_steps"] == int(finite.sum()) > 0
-    assert steps["tcg_steps"] == int(ls[finite].sum()) > 0
+    jax.effects_barrier()
+    assert steps["tr_steps"] == seen["tcg"] > 0
+    assert steps["tcg_steps"] == seen["cg"] > 0
+    assert steps["rsd_steps"] == seen["grad"] - seen["tcg"] > 0
+    assert steps["lbfgs_iters"] == seen["lbfgs"] > 0
     # passes x clusters x robust rounds x chunks, each one TR loop
     assert steps["tr_loops"] == cfg.max_emiter * NCLUS * \
         cfg.em_rounds_robust
@@ -247,10 +282,8 @@ def test_step_counts_match_the_telemetry_traces(tile):
     # the warm-up ahead of each TR loop runs at least one iteration and
     # at most its static ceiling, and moves x on some of them
     itmax_rsd = cfg.max_iter * cfg.iter_budget_cap + 5
-    assert 0 < steps["rsd_steps"] <= steps["tr_loops"] * itmax_rsd
+    assert steps["rsd_steps"] <= steps["tr_loops"] * itmax_rsd
     assert 0 <= steps["rsd_moved"] <= steps["rsd_steps"]
-    lb = np.asarray(res.telemetry["lbfgs"].cost)
-    assert steps["lbfgs_iters"] == int(np.isfinite(lb).sum()) > 0
 
 
 def test_modes_without_a_trust_region_count_zero(tile):
