@@ -6,10 +6,11 @@ Redesign of ``clevmar_der_single_nocuda`` / ``oslevmar_der_single_nocuda``
 Jacobian per cluster and runs one LM loop per hybrid chunk on pthreads.
 Here the structure of the RIME is exploited instead: each residual row
 (one baseline, 8F reals) depends only on the 16 parameters of its two
-stations, so J^T J is assembled from per-row 16x16 blocks scattered into a
-(nchunk, N, N, 8, 8) block grid, and J^T e from per-row 16-vectors — one
-fused pass over all rows for ALL hybrid chunks at once.  The LM iterations
-for all chunks then run in lock-step inside a single ``lax.while_loop``
+stations, so J^T J is assembled from per-row 16x16 blocks and J^T e from
+per-row 16-vectors, formed in closed form with rows on the lane axis and
+summed onto stations by one-hot matmuls — one pass over all rows for ALL
+hybrid chunks at once.  The LM iterations for all chunks then run in
+lock-step inside a single ``lax.while_loop``
 (per-chunk damping/acceptance state, masked once a chunk terminates), and
 the tiny dense (8N x 8N) solves are a vmapped Cholesky.  This removes the
 reference's pthread fan-out and its per-chunk sequential loop
@@ -27,16 +28,21 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import struct
 
-from sagecal_tpu.core.types import corrupt_flat, params_to_jones, reals_of_flat
+from sagecal_tpu.core.types import (
+    corrupt_flat,
+    gather_jones_rows,
+    params_to_jones,
+    reals_of_flat,
+)
 from sagecal_tpu.ops.quality import SolveQuality, residual_quality
 from sagecal_tpu.utils.precision import true_f32
 
-# Row-block size for the Jacobian-assembly scan: bounds the per-block
-# (RB, F*8, 8) Jacobian intermediates so assembly memory is O(block), not
-# O(rows) — at the 62-stn/100-cluster/60-ts shape the unblocked
-# intermediates would be ~1 GB each after TPU tile padding.
+# Row-block size for the normal-equation scan: bounds the per-block
+# Jacobian planes and (RB, K) one-hot station maps so assembly memory is
+# O(block), not O(rows).
 _ROW_BLOCK = 8192
 
 
@@ -73,29 +79,70 @@ def _residual_flat(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, sqrt_w):
     return r
 
 
-def _row_model(pp, qq, C, mask_row, sqrt_w_row):
-    """Model for ONE row as a function of its two stations' 16 params.
-
-    pp, qq: (8,) real params; C: (F,2,2) complex. Returns (F*8,) reals
-    ordered (f, i, j, re/im) — identical to one row of
-    :func:`_residual_flat`'s (F, 8) elements.
-    """
-    Jp = params_to_jones(pp)[0]  # (2,2)
-    Jq = params_to_jones(qq)[0]
-    m = Jp @ C @ jnp.conj(Jq.T)
-    r = jnp.stack([jnp.real(m), jnp.imag(m)], axis=-1) * mask_row[:, None, None, None]
-    r = r.reshape(-1)
-    if sqrt_w_row is not None:
-        r = r * sqrt_w_row
-    return r
-
-
 def _pad_rows(x, padr, axis=-1):
     if padr == 0:
         return x
     cfg = [(0, 0)] * x.ndim
     cfg[axis % x.ndim] = (0, padr)
     return jnp.pad(x, cfg)
+
+
+def _one_hot(idx, K, dtype):
+    """(rows,) indices -> (rows, K) one-hot: the right operand of a
+    matmul that sums rows onto K stations or station pairs."""
+    return (idx[:, None] == jnp.arange(K, dtype=idx.dtype)[None, :]).astype(dtype)
+
+
+def _per_chunk_sum(x, chunk_map, nchunk):
+    """(rows,) -> (nchunk,): each hybrid chunk's sum, as a dense masked
+    reduction (no scatter)."""
+    sel = chunk_map[None, :] == jnp.arange(nchunk, dtype=chunk_map.dtype)[:, None]
+    return jnp.sum(jnp.where(sel, x[None, :], 0), axis=-1)
+
+
+def _pair_index(lo, hi, N):
+    """Row-major index of the station pair lo <= hi in the upper triangle
+    (diagonal included) of an N x N grid: 0 .. N(N+1)/2 - 1."""
+    return lo * N - (lo * (lo - 1)) // 2 + (hi - lo)
+
+
+def _jacobian_planes(jones, coh, wt, ap, aq, cm):
+    """Weighted per-row Jacobians of the model, rows on the lane axis.
+
+    The model m = J_p G with G = C J_q^H, and m = H J_q^H with H = J_p C,
+    is linear in each station's entries, so every column of the real
+    Jacobian is an entry of G or H (times i for an imaginary part, and
+    conjugated for q).  With the output real (f, i, j, c) (c: re/im) and
+    the station parameter (a, b, s) of J_ab (s: re/im, flat index
+    4b + 2a + s, the params_to_jones order):
+
+      dm_ij / dJp_ab = delta_ia G_bj u_s,        u = (1, i)
+      dm_ij / dJq_ab = delta_ja H_ib conj(u_s)
+
+    Returns P, Q (F, i, j, c, b, s, RB): the nonzero entries, for the p
+    side at a = i and for the q side at a = j, each times the output's
+    weight ``wt`` (F, 8, RB) — the mask times the robust sqrt-weight.
+    """
+    F, RB = coh.shape[0], coh.shape[-1]
+    pa, pb, pc, pd = gather_jones_rows(jones, ap, cm)  # J_p 00, 01, 10, 11
+    qa, qb, qc, qd = (jnp.conj(x) for x in gather_jones_rows(jones, aq, cm))
+    c00, c01, c10, c11 = coh[:, 0], coh[:, 1], coh[:, 2], coh[:, 3]
+    G = jnp.stack([  # (F, b, j, RB)
+        jnp.stack([c00 * qa + c01 * qb, c00 * qc + c01 * qd], axis=1),
+        jnp.stack([c10 * qa + c11 * qb, c10 * qc + c11 * qd], axis=1),
+    ], axis=1)
+    H = jnp.stack([  # (F, i, b, RB)
+        jnp.stack([pa * c00 + pb * c10, pa * c01 + pb * c11], axis=1),
+        jnp.stack([pc * c00 + pd * c10, pc * c01 + pd * c11], axis=1),
+    ], axis=1)
+    Gr, Gi, Hr, Hi = jnp.real(G), jnp.imag(G), jnp.real(H), jnp.imag(H)
+    # [c][s]: the real (c) part of u_s g, and of conj(u_s) h
+    D = jnp.stack([jnp.stack([Gr, -Gi], 3), jnp.stack([Gi, Gr], 3)], 3)
+    E = jnp.stack([jnp.stack([Hr, Hi], 3), jnp.stack([Hi, -Hr], 3)], 3)
+    w = wt.reshape(F, 2, 2, 2, 1, 1, RB)  # (f, i, j, c)
+    P = w * jnp.transpose(D, (0, 2, 3, 1, 4, 5))[:, None]  # D (f, b, j, c, s)
+    Q = w * jnp.transpose(E, (0, 1, 3, 2, 4, 5))[:, :, None]  # E (f, i, b, c, s)
+    return P, Q
 
 
 def _assemble_normal_eq(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, nchunk, sqrt_w):
@@ -106,87 +153,99 @@ def _assemble_normal_eq(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, nchunk, 
     (the LM step solves (JTJ + mu I) dp = JTe).
 
     Each residual row depends only on its two stations' 16 parameters, so
-    J^T J is assembled from per-row 8x8 blocks scattered into an
-    (nchunk, N, N, 8, 8) grid — the TPU answer to the reference's full
-    (8*Nbase*tilesz x 8N) Jacobian materialization (clmfit.c).  Rows are
-    processed in blocks of ``_ROW_BLOCK`` under ``lax.scan`` so the
-    per-row mat-form intermediates stay bounded at any tile size.
+    J^T J is a sum of per-row 8x8 blocks (pp, pq, qq) — the TPU answer to
+    the reference's full (8*Nbase*tilesz x 8N) Jacobian (clmfit.c).  The
+    blocks are formed in closed form as (entries, rows) planes
+    (:func:`_jacobian_planes`) and summed over rows by one-hot matmuls:
+    the pp/qq blocks and the gradients onto (chunk, station), the pq
+    blocks onto (chunk, station pair).  No scatter: a scatter-add whose
+    indices repeat runs its updates one after another on the TPU.  Rows
+    are processed in blocks under ``lax.scan`` so the planes and one-hot
+    maps stay bounded at any tile size.
     """
     N = p_all.shape[-1] // 8
     dtype = p_all.dtype
     rows = ant_p.shape[0]
     F = vis.shape[-3]
+    npair = N * (N + 1) // 2
+    K1, K2 = nchunk * N, nchunk * npair
 
     e = _residual_flat(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, sqrt_w)
-    cost = jnp.zeros((nchunk,), dtype).at[chunk_map].add(jnp.sum(e * e, axis=(0, 1)))
+    cost = _per_chunk_sum(jnp.sum(e * e, axis=(0, 1)), chunk_map, nchunk)
 
-    pblk = p_all.reshape(nchunk, N, 8)
+    wt = mask[:, None, :] if sqrt_w is None else mask[:, None, :] * sqrt_w
+    wt = jnp.broadcast_to(wt, e.shape).astype(dtype)  # (F, 8, rows)
+    lo = jnp.minimum(ant_p, ant_q)
+    hi = jnp.maximum(ant_p, ant_q)
+    pair = chunk_map * npair + _pair_index(lo, hi, N)
+    swap = ant_p > ant_q
+    jones = params_to_jones(p_all)
 
     nblk = -(-rows // _ROW_BLOCK)
     RB = -(-rows // nblk)
     padr = nblk * RB - rows
-    coh_b = jnp.moveaxis(
-        _pad_rows(coh, padr).reshape(F, 4, nblk, RB), 2, 0
-    )  # (nblk, F, 4, RB)
-    mask_b = jnp.moveaxis(_pad_rows(mask, padr).reshape(F, nblk, RB), 1, 0)
-    e_b = jnp.moveaxis(_pad_rows(e, padr).reshape(F, 8, nblk, RB), 2, 0)
-    ap_b = _pad_rows(ant_p, padr).reshape(nblk, RB)
-    aq_b = _pad_rows(ant_q, padr).reshape(nblk, RB)
-    cm_b = _pad_rows(chunk_map, padr).reshape(nblk, RB)
-    with_w = sqrt_w is not None
-    if with_w:
-        sw_full = jnp.broadcast_to(sqrt_w, e.shape)
-        sw_b = jnp.moveaxis(_pad_rows(sw_full, padr).reshape(F, 8, nblk, RB), 2, 0)
-    else:
-        sw_b = jnp.zeros((nblk, 1, 1, 1), dtype)  # unused placeholder
 
-    jac_fn = jax.vmap(
-        jax.jacfwd(_row_model, argnums=(0, 1)),
-        in_axes=(0, 0, 0, 0, 0 if with_w else None),
-    )
+    def blocks(x):  # (..., rows) -> (nblk, ..., RB); padded rows weigh 0
+        x = _pad_rows(x, padr).reshape(x.shape[:-1] + (nblk, RB))
+        return jnp.moveaxis(x, -2, 0)
 
     def block(carry, xs):
-        JTJ, JTe = carry
-        coh_k, mask_k, e_k, ap, aq, cm, sw_k = xs
-        C = jnp.moveaxis(coh_k, -1, 0).reshape(RB, F, 2, 2)
-        mrow = jnp.moveaxis(mask_k, -1, 0)  # (RB, F)
-        erow = jnp.moveaxis(e_k, -1, 0).reshape(RB, F * 8)
-        swrow = (
-            jnp.moveaxis(sw_k, -1, 0).reshape(RB, F * 8) if with_w else None
-        )
-        pp = pblk[cm, ap]  # (RB, 8)
-        qq = pblk[cm, aq]
-        Jp, Jq = jac_fn(pp, qq, C, mrow, swrow)  # (RB, F8, 8) each
-        App = jnp.einsum("rki,rkj->rij", Jp, Jp)
-        Apq = jnp.einsum("rki,rkj->rij", Jp, Jq)
-        Aqq = jnp.einsum("rki,rkj->rij", Jq, Jq)
-        gp = jnp.einsum("rki,rk->ri", Jp, erow)
-        gq = jnp.einsum("rki,rk->ri", Jq, erow)
-        JTJ = JTJ.at[cm, ap, ap].add(App)
-        JTJ = JTJ.at[cm, ap, aq].add(Apq)
-        JTJ = JTJ.at[cm, aq, ap].add(jnp.swapaxes(Apq, -1, -2))
-        JTJ = JTJ.at[cm, aq, aq].add(Aqq)
-        JTe = JTe.at[cm, ap].add(gp)
-        JTe = JTe.at[cm, aq].add(gq)
-        return (JTJ, JTe), None
+        diag, off = carry
+        coh_k, wt_k, e_k, ap, aq, cm, pair_k, swap_k = xs
+        P, Q = _jacobian_planes(jones, coh_k, wt_k, ap, aq, cm)
+        e6 = e_k.reshape(F, 2, 2, 2, 1, 1, RB)
+        # pp: sum over (f, j, c) at a = i; qq: over (f, i, c) at a = j;
+        # pq: over (f, c) with a = i on the p side and a' = j on the q side
+        Pl, Pr = P[..., None, None, :], P[:, :, :, :, None, None]
+        Ql, Qr = Q[..., None, None, :], Q[:, :, :, :, None, None]
+        App = jnp.sum(Pl * Pr, axis=(0, 2, 3))  # (a, b, s, b', s', RB)
+        Aqq = jnp.sum(Ql * Qr, axis=(0, 1, 3))
+        Apq = jnp.sum(Pl * Qr, axis=(0, 3))  # (a, a', b, s, b', s', RB)
+        gp = jnp.sum(P * e6, axis=(0, 2, 3))  # (a, b, s, RB)
+        gq = jnp.sum(Q * e6, axis=(0, 1, 3))
+        # a row with p > q lands on the pair (q, p) as its pq block's
+        # transpose
+        Apq = jnp.where(swap_k, jnp.transpose(Apq, (1, 0, 4, 5, 2, 3, 6)), Apq)
+        Dp = jnp.concatenate([App.reshape(32, RB), gp.reshape(8, RB)])
+        Dq = jnp.concatenate([Aqq.reshape(32, RB), gq.reshape(8, RB)])
+        diag = (diag + Dp @ _one_hot(cm * N + ap, K1, dtype)
+                + Dq @ _one_hot(cm * N + aq, K1, dtype))
+        off = off + Apq.reshape(64, RB) @ _one_hot(pair_k, K2, dtype)
+        return (diag, off), None
 
     from sagecal_tpu.utils.platform import match_vma
 
-    JTJ0 = jnp.zeros((nchunk, N, N, 8, 8), dtype)
-    JTe0 = jnp.zeros((nchunk, N, 8), dtype)
-    (JTJ, JTe), _ = jax.lax.scan(
-        block, match_vma((JTJ0, JTe0), e), (coh_b, mask_b, e_b, ap_b, aq_b, cm_b, sw_b)
-    )
-    JTJ = JTJ.transpose(0, 1, 3, 2, 4).reshape(nchunk, 8 * N, 8 * N)
-    JTe = JTe.reshape(nchunk, 8 * N)
+    xs = (blocks(coh), blocks(wt), blocks(e), blocks(ant_p), blocks(ant_q),
+          blocks(chunk_map), blocks(pair), blocks(swap))
+    init = (jnp.zeros((40, K1), dtype), jnp.zeros((64, K2), dtype))
+    (diag, off), _ = jax.lax.scan(block, match_vma(init, e), xs)
+
+    # pair sums -> the (N, N) grid by a static gather: the pair's block
+    # above the diagonal, its transpose below, both on it
+    pq = np.arange(N)
+    lo_s, hi_s = np.minimum.outer(pq, pq), np.maximum.outer(pq, pq)
+    grid = off.reshape(2, 2, 2, 2, 2, 2, nchunk, npair)[
+        ..., _pair_index(lo_s, hi_s, N).reshape(-1)]
+    upper = (pq[:, None] <= pq[None, :]).reshape(-1)
+    lower = (pq[:, None] >= pq[None, :]).reshape(-1)
+    grid = (jnp.where(upper, grid, 0)
+            + jnp.where(lower, jnp.transpose(grid, (1, 0, 4, 5, 2, 3, 6, 7)), 0))
+    grid = grid.reshape(2, 2, 2, 2, 2, 2, nchunk, N, N)
+    # pp + qq blocks on the diagonal; they couple only a = a'
+    App = diag[:32].reshape(2, 1, 2, 2, 2, 2, nchunk, N, 1)
+    eye2 = jnp.asarray(np.eye(2).reshape(2, 2, 1, 1, 1, 1, 1, 1, 1), dtype)
+    grid = grid + App * eye2 * jnp.asarray(np.eye(N), dtype)
+    # (a, a', b, s, b', s', c, p, q) -> (c, p(b, a, s), q(b', a', s'))
+    JTJ = jnp.transpose(grid, (6, 7, 2, 0, 3, 8, 4, 1, 5)).reshape(
+        nchunk, 8 * N, 8 * N)
+    JTe = jnp.transpose(diag[32:].reshape(2, 2, 2, nchunk, N),
+                        (3, 4, 1, 0, 2)).reshape(nchunk, 8 * N)
     return JTJ, JTe, cost
 
 
 def _cost_only(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, nchunk, sqrt_w):
     e = _residual_flat(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, sqrt_w)
-    return jnp.zeros((nchunk,), p_all.dtype).at[chunk_map].add(
-        jnp.sum(e * e, axis=(0, 1))
-    )
+    return _per_chunk_sum(jnp.sum(e * e, axis=(0, 1)), chunk_map, nchunk)
 
 
 def _solve_spd(A, b):

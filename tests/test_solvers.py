@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from sagecal_tpu.core.types import jones_to_params, params_to_jones, identity_jones
 from sagecal_tpu.io.simulate import corrupt_and_observe, make_visdata, random_jones
@@ -168,6 +169,7 @@ def test_lbfgs_f32_no_nan_after_converged_em():
 
     code = """
 import numpy as np
+import pytest
 import jax
 jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
@@ -198,3 +200,148 @@ print("F32OK")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=300, env=env)
     assert r.returncode == 0 and "F32OK" in r.stdout, r.stdout + r.stderr
+
+
+# --- normal-equation assembly: closed-form planes + one-hot reductions ---
+
+
+def _row_model_reference(pp, qq, C, mask_row, sqrt_w_row):
+    """One row's weighted model reals (F*8,) from its two stations' 16
+    params: the autodiff reference the assembly is checked against."""
+    Jp = params_to_jones(pp)[0]
+    Jq = params_to_jones(qq)[0]
+    m = Jp @ C @ jnp.conj(Jq.T)
+    r = jnp.stack([jnp.real(m), jnp.imag(m)], axis=-1) * mask_row[:, None, None, None]
+    r = r.reshape(-1)
+    if sqrt_w_row is not None:
+        r = r * sqrt_w_row
+    return r
+
+
+def _normal_eq_reference(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, nchunk, sqrt_w):
+    """(JTJ, JTe, cost) from per-row vmap(jacfwd) Jacobians scattered into
+    the (nchunk, N, N, 8, 8) block grid, one row at a time."""
+    from sagecal_tpu.solvers.lm import _residual_flat
+
+    N = p_all.shape[-1] // 8
+    F = vis.shape[-3]
+    rows = ant_p.shape[0]
+    e = _residual_flat(p_all, coh, vis, mask, ant_p, ant_q, chunk_map, sqrt_w)
+    cost = jnp.zeros((nchunk,), p_all.dtype).at[chunk_map].add(jnp.sum(e * e, axis=(0, 1)))
+    pblk = p_all.reshape(nchunk, N, 8)
+    C = jnp.moveaxis(coh, -1, 0).reshape(rows, F, 2, 2)
+    swrow = None
+    if sqrt_w is not None:
+        swrow = jnp.moveaxis(jnp.broadcast_to(sqrt_w, e.shape), -1, 0).reshape(rows, F * 8)
+    jac = jax.vmap(jax.jacfwd(_row_model_reference, argnums=(0, 1)),
+                   in_axes=(0, 0, 0, 0, None if sqrt_w is None else 0))
+    Jp, Jq = jac(pblk[chunk_map, ant_p], pblk[chunk_map, ant_q], C,
+                 jnp.moveaxis(mask, -1, 0), swrow)
+    erow = jnp.moveaxis(e, -1, 0).reshape(rows, F * 8)
+    Apq = jnp.einsum("rki,rkj->rij", Jp, Jq)
+    JTJ = jnp.zeros((nchunk, N, N, 8, 8), p_all.dtype)
+    JTJ = JTJ.at[chunk_map, ant_p, ant_p].add(jnp.einsum("rki,rkj->rij", Jp, Jp))
+    JTJ = JTJ.at[chunk_map, ant_p, ant_q].add(Apq)
+    JTJ = JTJ.at[chunk_map, ant_q, ant_p].add(jnp.swapaxes(Apq, -1, -2))
+    JTJ = JTJ.at[chunk_map, ant_q, ant_q].add(jnp.einsum("rki,rkj->rij", Jq, Jq))
+    JTe = jnp.zeros((nchunk, N, 8), p_all.dtype)
+    JTe = JTe.at[chunk_map, ant_p].add(jnp.einsum("rki,rk->ri", Jp, erow))
+    JTe = JTe.at[chunk_map, ant_q].add(jnp.einsum("rki,rk->ri", Jq, erow))
+    JTJ = JTJ.transpose(0, 1, 3, 2, 4).reshape(nchunk, 8 * N, 8 * N)
+    return JTJ, JTe.reshape(nchunk, 8 * N), cost
+
+
+def _random_lm_problem(nchunk, F, weighted, N=7, tilesz=3, seed=5):
+    """f64 rows over every station pair, some written q-before-p and one
+    autocorrelation per timeslot, half of them masked."""
+    rng = np.random.default_rng(seed)
+    pairs = [(p, q) for p in range(N) for q in range(p + 1, N)] + [(2, 2)]
+    ap = np.array([p for _ in range(tilesz) for p, _ in pairs], np.int32)
+    aq = np.array([q for _ in range(tilesz) for _, q in pairs], np.int32)
+    flip = rng.random(ap.size) < 0.5
+    ap, aq = np.where(flip, aq, ap), np.where(flip, ap, aq)
+    rows = ap.size
+    cmap = np.minimum(np.repeat(np.arange(tilesz), len(pairs)) * nchunk // tilesz,
+                      nchunk - 1).astype(np.int32)
+    cplx = lambda *s: rng.standard_normal(s) + 1j * rng.standard_normal(s)  # noqa: E731
+    coh, vis = cplx(F, 4, rows), cplx(F, 4, rows)
+    mask = np.ones((F, rows))
+    mask[:, rng.permutation(rows)[: rows // 2]] = 0.0
+    J = np.eye(2) + 0.3 * cplx(nchunk, N, 2, 2)
+    sqrt_w = rng.uniform(0.2, 1.5, (F, 8, rows)) if weighted else None
+    p = jones_to_params(jnp.asarray(J))
+    return (p, jnp.asarray(coh), jnp.asarray(vis), jnp.asarray(mask),
+            jnp.asarray(ap), jnp.asarray(aq), jnp.asarray(cmap), nchunk,
+            None if sqrt_w is None else jnp.asarray(sqrt_w))
+
+
+@pytest.mark.parametrize("nchunk", [1, 3])
+@pytest.mark.parametrize("F", [1, 2])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_normal_eq_matches_autodiff_scatter_reference(nchunk, F, weighted):
+    from sagecal_tpu.solvers.lm import _assemble_normal_eq
+
+    args = _random_lm_problem(nchunk, F, weighted)
+    assert args[0].dtype == jnp.float64
+    got = _assemble_normal_eq(*args)
+    want = _normal_eq_reference(*args)
+    for name, g, w in zip(("JTJ", "JTe", "cost"), got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.shape == w.shape, name
+        err = np.max(np.abs(g - w)) / np.max(np.abs(w))
+        assert err < 1e-10, (name, err)
+
+
+def test_lm_solve_admm_matches_autodiff_scatter_reference(monkeypatch):
+    from sagecal_tpu.solvers import lm
+
+    p0, coh, _, mask, ap, aq, cmap, nchunk, _ = _random_lm_problem(2, 2, False, seed=8)
+    rng = np.random.default_rng(8)
+    J = np.eye(2) + 0.2 * (rng.standard_normal((nchunk, 7, 2, 2))
+                           + 1j * rng.standard_normal((nchunk, 7, 2, 2)))
+    from sagecal_tpu.core.types import corrupt_flat
+
+    vis = corrupt_flat(jnp.asarray(J), coh, ap, aq, cmap)
+    admm = dict(admm_y=0.01 * jnp.asarray(rng.standard_normal(p0.shape)),
+                admm_bz=p0 + 0.05 * jnp.asarray(rng.standard_normal(p0.shape)),
+                admm_rho=jnp.asarray(2.0))
+
+    def solve():
+        return lm_solve(vis, coh, mask, ap, aq, cmap, p0, LMConfig(itmax=6), **admm)
+
+    new = solve()
+
+    def cost_reference(*a):
+        return _normal_eq_reference(*a)[2]
+
+    monkeypatch.setattr(lm, "_assemble_normal_eq", _normal_eq_reference)
+    monkeypatch.setattr(lm, "_cost_only", cost_reference)
+    old = solve()
+    assert float(jnp.max(jnp.abs(new.p - p0))) > 1e-2  # the solve moved
+    np.testing.assert_allclose(np.asarray(new.p), np.asarray(old.p), rtol=0, atol=1e-8)
+    np.testing.assert_allclose(np.asarray(new.cost), np.asarray(old.cost), rtol=1e-10)
+
+
+def test_lm_solve_assembles_without_float_scatter():
+    """At one consensus band's shape (62 stations, 18,910 rows, one
+    channel, ADMM terms on) the lowered solve holds no scatter into
+    floating-point data: the normal equations and chunk costs are dense
+    reductions, run at HIGHEST precision.  The only scatters left are the
+    LU's integer pivot swaps (the jitter fallback of ``_solve_spd``)."""
+    import re
+
+    N, rows = 62, 18910
+    S = jax.ShapeDtypeStruct
+    f32, c64, i32 = jnp.float32, jnp.complex64, jnp.int32
+    args = (S((1, 4, rows), c64), S((1, 4, rows), c64), S((1, rows), f32),
+            S((rows,), i32), S((rows,), i32), S((rows,), i32), S((1, 8 * N), f32))
+    admm = dict(admm_y=S((1, 8 * N), f32), admm_bz=S((1, 8 * N), f32),
+                admm_rho=S((), f32))
+    text = jax.jit(lambda *a, **k: lm_solve(*a, LMConfig(itmax=4), **k)).lower(
+        *args, **admm).as_text()
+    results = re.findall(r"stablehlo\.scatter.*?\}\) : \([^)]*\) -> (tensor<[^>]*>)",
+                         text, re.S)
+    assert results and all(t.endswith("xi32>") for t in results), results
+    precisions = re.findall(r"precision = \[(\w+), (\w+)\]", text)
+    assert text.count("stablehlo.dot_general") == len(precisions) > 0
+    assert set(precisions) == {("HIGHEST", "HIGHEST")}
